@@ -1,8 +1,8 @@
-"""Kernel micro-benchmarks: Pallas (interpret) vs jnp oracle vs numpy.
+"""Kernel micro-benchmarks: Pallas vs jnp oracle vs numpy.
 
-On this CPU container interpret-mode timing only proves correctness-path
-cost; the derived column reports achieved GB/s for the oracle (the XLA-
-compiled path) which is the deployable CPU number.
+On the CPU the Pallas kernels run in interpret mode, so their timing there
+is only the cost of the correctness path; the derived column reports
+achieved GB/s for the oracle (the XLA-compiled path).
 
 ``bench_kernel_fused`` sweeps fused-kernel block sizes per app-monoid and
 validates the roofline autotuner's pick against a measured grid search;
@@ -45,21 +45,9 @@ def bench_segment_sum():
         emit(f"kern.segsum.ref.E{E}", t_ref * 1e6, f"GBps={gbps:.2f}")
         if E <= 1 << 16:   # interpret mode is slow; validate small only
             t_pal = _time(lambda a, b: ops.segment_sum(a, b, R), c, d)
-            emit(f"kern.segsum.pallas_interp.E{E}", t_pal * 1e6,
-                 "interpret=True (correctness path)")
-
-
-def bench_compact():
-    from repro.kernels import ops, ref
-
-    rng = np.random.default_rng(0)
-    n = 1 << 18
-    mask = jnp.asarray(rng.random(n) < 0.2)
-    vals = jnp.asarray(rng.normal(size=n).astype(np.float32))
-    K = int(0.4 * n)
-    t_ref = _time(lambda m, v: ref.compact(m, v, K), mask, vals)
-    emit(f"kern.compact.ref.n{n}", t_ref * 1e6,
-         f"GBps={n*5/t_ref/1e9:.2f}")
+            path = "interp" if ops.interpret_mode() else "mosaic"
+            emit(f"kern.segsum.pallas_{path}.E{E}", t_pal * 1e6,
+                 f"interpret={ops.interpret_mode()}")
 
 
 def bench_gab_superstep():
@@ -113,6 +101,7 @@ def bench_kernel_fused():
     """
     import jax
     from repro.kernels.gab_fused import FusedSpec, gab_fused
+    from repro.kernels.ops import interpret_mode
     from repro.roofline import kernel_tune
 
     smoke = common.SMOKE
@@ -144,7 +133,7 @@ def bench_kernel_fused():
         choice = kernel_tune.pick_blocks(spec.combine, q, edge_cap, row_cap)
         grid = [(128, 128), (256, 256), kernel_tune.STATIC_BLOCKS,
                 choice.blocks]
-        budget = int(kernel_tune._VMEM_FRACTION * kernel_tune.hw.VMEM_BYTES)
+        budget = kernel_tune.vmem_budget()
         grid = [g for g in dict.fromkeys(grid)
                 if kernel_tune.vmem_plan_bytes(spec.combine, q, *g)
                 <= budget]
@@ -152,7 +141,8 @@ def bench_kernel_fused():
         timed = {}
         for be, br in grid:
             t = _time(lambda: gab_fused(spec, sv, a, b, dst, old, None, nr,
-                                        row_cap, block_e=be, block_r=br),
+                                        row_cap, block_e=be, block_r=br,
+                                        interpret=interpret_mode()),
                       iters=2 if smoke else 3)
             timed[(be, br)] = t
             emit(f"kern.fused.{app}.BE{be}_BR{br}", t * 1e6,
@@ -198,5 +188,4 @@ def bench_kernel_fused():
     })
 
 
-ALL = [bench_segment_sum, bench_compact, bench_gab_superstep,
-       bench_kernel_fused]
+ALL = [bench_segment_sum, bench_gab_superstep, bench_kernel_fused]

@@ -32,22 +32,12 @@ def zstd_compress(data: bytes, level: int = 3) -> bytes:
 
 
 def shard_map_unchecked(f, mesh, in_specs, out_specs):
-    """``shard_map`` with replication checking off, across jax versions:
-    the entry point moved (jax.experimental -> jax.shard_map) and the
-    kwarg was renamed (check_rep -> check_vma).  jax is imported lazily so
-    jax-free consumers of this module stay jax-free."""
+    """``jax.shard_map`` with replication checking off.  jax is imported
+    lazily so jax-free consumers of this module stay jax-free."""
     import jax
 
-    try:
-        sm = jax.shard_map
-    except AttributeError:  # jax < 0.6
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    except TypeError:  # jax < 0.6
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def zstd_decompress(data: bytes) -> bytes:

@@ -195,7 +195,7 @@ def _fused_tile(prog, fs, src_vals, src_aux, edge_val, dst_local, old,
         _gf.DEFAULT_BLOCK_E, _gf.DEFAULT_BLOCK_R)
     return _gf.gab_fused(
         fs, src_vals, a, b, dst_local, old, base, num_rows, row_cap,
-        block_e=be, block_r=br, interpret=_kops._interpret(),
+        block_e=be, block_r=br, interpret=_kops.interpret_mode(),
     )
 
 

@@ -1,4 +1,4 @@
-"""Pallas kernels for the GAB hot loop (gather/combine/apply, compaction).
+"""Pallas kernels for the GAB hot loop (gather/combine/apply).
 
 OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY for compute
 hot-spots the paper itself optimizes with a custom kernel. Leave this

@@ -14,18 +14,26 @@ elementwise ops over the row block.  This kernel fuses the whole chain:
   * the output row block stays resident in a VMEM accumulator across the
     whole edge contraction (grid is 1-D over row blocks; the edge loop is
     a ``fori_loop`` inside the kernel),
+  * each row block visits only the edge blocks that hold one of its edges:
+    the wrapper computes per-row-block ``[lo, hi)`` edge-block bounds
+    (contiguous ranges for the dst-sorted tiles the engine builds) and
+    the kernel reads them from SMEM.  Without the bound every row block
+    streams the whole tile: Q·E·R one-hot MACs per tile, 2.2e11 at Q=8
+    on a Graph500 scale-22 tile of 160k edges × 172k rows, where the
+    bounded loop does about Q·(E·BR + R·BE),
   * apply (damped affine update / min-max relaxation) and the per-
     ``(vertex, query)`` updated mask are computed in-kernel before the
     single write-back of the row block.
 
-Per row block of ``BR`` rows the kernel reads ``E × (Q + #streams)`` f32
-lanes and writes ``BR × Q`` twice (values + mask) — the contrib array,
+Per row block of ``BR`` rows the kernel reads its edge blocks ×
+``BE × (Q + #streams)`` f32 lanes and writes ``BR × Q`` twice (values + mask) — the contrib array,
 the accumulator round-trip, and the mask pass never touch HBM.
 
 Bit-identity contract: with equal ``(BE, BR)`` the accumulation order is
 exactly the unfused one-hot kernel's (identity-init, ascending edge
-blocks, the same ``dot_general``/masked-select per block), and the apply
-formulas mirror ``core/apps.py`` term-for-term.  The one caveat is the
+blocks, the same ``dot_general``/masked-select per block; a skipped block
+holds no edge of the row block, so it would only have added the monoid
+identity), and the apply formulas mirror ``core/apps.py`` term-for-term.  The one caveat is the
 apply's multiply-add: XLA may contract the *unfused* path's
 ``alpha*base + beta*accum`` into an FMA (it does on CPU whenever the row
 offset is traced, and deletes ``optimization_barrier``/bitcast pins that
@@ -88,9 +96,10 @@ class FusedSpec:
     update_tol: float = 0.0
 
 
-def _kernel(spec: FusedSpec, block_e: int, block_r: int, n_eblocks: int,
-            nr_ref, *refs):
-    """Grid = (num_row_blocks,).  Streams every edge block through a 2-slot
+def _kernel(spec: FusedSpec, block_e: int, block_r: int,
+            nr_ref, bounds_ref, *refs):
+    """Grid = (num_row_blocks,).  Streams edge blocks ``[lo, hi)`` of this
+    row block (``bounds_ref[2j]``, ``bounds_ref[2j + 1]``) through a 2-slot
     VMEM scratch with overlapped DMA, accumulating into ``acc``; applies the
     vertex update + mask once at the end and writes the row block back."""
     # unpack the spec-dependent ref list: HBM streams, row-blocked ins/outs,
@@ -134,15 +143,20 @@ def _kernel(spec: FusedSpec, block_e: int, block_r: int, n_eblocks: int,
         for cp in copies(i, slot):
             cp.wait()
 
+    lo = bounds_ref[2 * j]
+    hi = bounds_ref[2 * j + 1]
     acc[...] = jnp.full_like(acc, _IDENTITY[combine])
-    start(0, 0)
+
+    @pl.when(lo < hi)
+    def _first():
+        start(lo, 0)
 
     def body(i, _):
-        slot = jax.lax.rem(i, 2)
+        slot = jax.lax.rem(i - lo, 2)
 
-        @pl.when(i + 1 < n_eblocks)
+        @pl.when(i + 1 < hi)
         def _prefetch():
-            start(i + 1, jax.lax.rem(i + 1, 2))
+            start(i + 1, jax.lax.rem(i + 1 - lo, 2))
 
         wait(i, slot)
         src = src_s[slot]                       # [qp, BE]
@@ -164,6 +178,7 @@ def _kernel(spec: FusedSpec, block_e: int, block_r: int, n_eblocks: int,
             part = jax.lax.dot_general(
                 contrib, h,
                 dimension_numbers=(((1,), (0,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
                 preferred_element_type=jnp.float32,
             )                                   # [qp, BR] on the MXU
             acc[...] += part
@@ -177,7 +192,7 @@ def _kernel(spec: FusedSpec, block_e: int, block_r: int, n_eblocks: int,
                         else jnp.maximum(cur, red))
         return 0
 
-    jax.lax.fori_loop(0, n_eblocks, body, 0)
+    jax.lax.fori_loop(lo, hi, body, 0)
 
     # ---- fused apply + updated mask on the resident row block -----------
     accum = acc[...]                            # [qp, BR]
@@ -206,6 +221,23 @@ def _kernel(spec: FusedSpec, block_e: int, block_r: int, n_eblocks: int,
     upd_ref[...] = jnp.logical_and(valid, upd).astype(jnp.float32)
 
 
+def _edge_block_bounds(dst: jax.Array, block_e: int, block_r: int,
+                       n_rblocks: int) -> jax.Array:
+    """Per row block ``j``, the edge blocks ``[lo_j, hi_j)`` spanning every
+    edge whose dst falls in it, from padded dst ``[E_pad]``; flattened as
+    ``[lo_0, hi_0, lo_1, hi_1, ...]`` (int32 ``[2 * n_rblocks]``) for SMEM.
+    Edges routed past the last row block are never hit and are left out;
+    a row block with no edge gets ``lo == hi == 0``."""
+    e = jnp.arange(dst.shape[0], dtype=jnp.int32)
+    rb = dst // block_r
+    first = jax.ops.segment_min(e, rb, num_segments=n_rblocks)
+    last = jax.ops.segment_max(e, rb, num_segments=n_rblocks)
+    has = last >= first
+    lo = jnp.where(has, first // block_e, 0)
+    hi = jnp.where(has, last // block_e + 1, 0)
+    return jnp.stack([lo, hi], axis=1).reshape(-1).astype(jnp.int32)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("spec", "row_cap", "block_e", "block_r", "interpret"),
@@ -222,7 +254,8 @@ def gab_fused(
     row_cap: int,
     block_e: int = DEFAULT_BLOCK_E,
     block_r: int = DEFAULT_BLOCK_R,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> tuple[jax.Array, jax.Array]:
     """One fused Gather+Apply tile step.
 
@@ -234,7 +267,9 @@ def gab_fused(
     tail: rows at or beyond ``num_rows`` keep ``old`` and are not-updated.
     Padding edges (``dst_local == row_cap``) reduce into the sink row,
     which lives past the returned slice — identical discard semantics to
-    the unfused ``num_segments = row_cap + 1`` convention.
+    the unfused ``num_segments = row_cap + 1`` convention.  ``interpret``
+    runs the kernel body on the host backend
+    (``kernels.ops.interpret_mode()``: CPU only).
     """
     assert src_vals.ndim in (1, 2) and old.ndim == src_vals.ndim
     squeeze = src_vals.ndim == 1
@@ -245,7 +280,6 @@ def gab_fused(
     e_pad = max(-(-e // block_e) * block_e, block_e)
     r_pad = max(-(-row_cap // block_r) * block_r, block_r)
     q_pad = max(-(-q // SUBLANES) * SUBLANES, SUBLANES)
-    n_eblocks = e_pad // block_e
 
     def prep_edge(x, fill=0.0):
         return _pad_axis(x.astype(jnp.float32)[None, :], e_pad, fill, axis=1)
@@ -260,11 +294,14 @@ def gab_fused(
                       q_pad, 0.0, axis=0)
     dst_p = _pad_axis(dst_local.astype(jnp.int32), e_pad,
                       jnp.int32(r_pad))[None, :]
+    bounds = _edge_block_bounds(dst_p[0], block_e, block_r, r_pad // block_r)
 
-    hbm = pl.BlockSpec(memory_space=pltpu.ANY)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     rowblk = pl.BlockSpec((q_pad, block_r), lambda j: (0, j))
-    in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM), hbm, hbm]
-    inputs = [jnp.asarray(num_rows, jnp.int32).reshape(1), dst_p, src_p]
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    in_specs = [smem, smem, hbm, hbm]
+    inputs = [jnp.asarray(num_rows, jnp.int32).reshape(1), bounds, dst_p,
+              src_p]
     if spec.scale_aux:
         in_specs.append(hbm)
         inputs.append(prep_edge(a))
@@ -292,7 +329,7 @@ def gab_fused(
     scratch.append(pltpu.SemaphoreType.DMA((2, n_streams)))
 
     new_p, upd_p = pl.pallas_call(
-        functools.partial(_kernel, spec, block_e, block_r, n_eblocks),
+        functools.partial(_kernel, spec, block_e, block_r),
         grid=(r_pad // block_r,),
         in_specs=in_specs,
         out_specs=[rowblk, rowblk],

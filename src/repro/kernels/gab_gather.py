@@ -9,6 +9,9 @@ reduction into dense systolic work* (DESIGN.md §3/§4):
                matrix ``H[e, r] = (dst[e] == j*BR + r)`` in VMEM and
                accumulate ``contrib @ H`` on the MXU — each edge block
                costs Q x BE x BR MACs, turning gather-scatter into matmul.
+               The contraction asks for HIGHEST (f32) precision: Mosaic's
+               default rounds f32 operands to bf16, which on a v5e put
+               PageRank contributions 0.3% off the f32 sum.
   min/max:     same tiling, but a masked VPU reduction over the edge axis
                (select + min), since min-plus has no MXU form.
 
@@ -20,10 +23,12 @@ GEMM at Q>1 and MXU utilization rises with the batch for free (H is built
 once per block regardless of Q).
 
 Block sizes default to (BE, BR) = (512, 256): H is 512x256 f32 = 512 KB of
-VMEM, contrib block Q x 2 KB, out block Q x 1 KB — comfortably inside the
-~16 MB v5e VMEM budget with double buffering up to Q ~ few hundred (the
-min/max select materializes [Q, BE, BR]; shrink BE/BR for very large Q).
-All dims are multiples of 128 for MXU/lane alignment.  The edge-block axis
+VMEM, the contrib block Q x 2 KB, the out block Q x 1 KB.  The min/max
+select materializes [Q, BE, BR] (4 MiB at Q=8), so very large Q or blocks
+need smaller BE/BR; the v5e compiler accepts the fused kernel's plans of
+tens of MiB and refuses a (4096, 2048) min block for VMEM
+(tests/test_tpu_compile.py pins both).  All dims are multiples of 128 for
+MXU/lane alignment.  The edge-block axis
 is the innermost grid dimension so the output row block stays resident
 across the whole contraction.
 """
@@ -63,6 +68,7 @@ def _kernel(dst_ref, contrib_ref, out_ref, *, block_r: int, combine: str):
         acc = jax.lax.dot_general(
             c, h,
             dimension_numbers=(((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32,
         )                                   # [Q, BR] on the MXU
         out_ref[...] += acc.astype(out_ref.dtype)
@@ -95,7 +101,8 @@ def segment_reduce_pallas(
     combine: str = "sum",
     block_e: int = DEFAULT_BLOCK_E,
     block_r: int = DEFAULT_BLOCK_R,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     """Segment-reduce ``contrib`` by ``dst`` into ``num_segments`` buckets.
 
@@ -103,7 +110,8 @@ def segment_reduce_pallas(
     (returns ``[num_segments, Q]``).  Shapes are padded to block multiples;
     padded edges use an out-of-range dst so they never hit a one-hot lane —
     an edge block made entirely of padding contributes only identities.
-    dtype follows ``contrib``.
+    dtype follows ``contrib``.  ``interpret`` runs the kernel body on the
+    host backend (``kernels.ops.interpret_mode()``: CPU only).
     """
     assert contrib.ndim in (1, 2) and dst.ndim == 1
     assert contrib.shape[0] == dst.shape[0]

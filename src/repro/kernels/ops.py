@@ -1,38 +1,32 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On this CPU container the kernels run with interpret=True (Pallas executes
-the kernel body with the XLA CPU backend); on a real TPU set
-REPRO_PALLAS_INTERPRET=0 (or rely on the backend auto-detect) to compile
-with Mosaic.  The one-hot compaction path needs indices < 2^24 (f32 lane
-exactness) and falls back to the jnp oracle beyond that.
+The kernels are written for the TPU and compile with Mosaic there.  On the
+CPU backend, and only there, they run in interpret mode (Pallas executes
+the kernel body with the XLA CPU backend) — the tests' rehearsal of the
+same kernel code.  Any other backend gets the Mosaic lowering and fails
+loudly rather than silently interpreting.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import compact as _compact
 from repro.kernels import gab_gather as _gg
 from repro.kernels import ref as _ref
 
 
-def _interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    # TPU compiles with Mosaic, GPU with Triton; only CPU (and anything
-    # else without a Pallas lowering) needs the interpreter.
-    return jax.default_backend() not in ("tpu", "gpu")
+def interpret_mode() -> bool:
+    """True iff the default backend is the CPU: the kernels' ``interpret``
+    argument for every call site."""
+    return jax.default_backend() == "cpu"
 
 
 def _needs_exact_fallback(contrib: jax.Array) -> bool:
     """True when the f32 round-trip inside the kernel could lose bits.
 
     The one-hot kernel computes in f32, which represents integers exactly
-    only up to 2^24.  Same guard shape as the compaction path below: decide
-    statically from dtype (int8/int16 always fit; wider ints may not).
+    only up to 2^24, so decide statically from dtype (int8/int16 always
+    fit; wider ints may not).
     """
     return (jnp.issubdtype(contrib.dtype, jnp.integer)
             and contrib.dtype.itemsize >= 4)
@@ -47,7 +41,7 @@ def segment_sum(contrib: jax.Array, dst: jax.Array, num_segments: int,
         return _ref.segment_sum(contrib, dst, num_segments)
     return _gg.segment_reduce_pallas(
         contrib, dst, num_segments, combine="sum",
-        block_e=block_e, block_r=block_r, interpret=_interpret(),
+        block_e=block_e, block_r=block_r, interpret=interpret_mode(),
     )
 
 
@@ -60,7 +54,7 @@ def segment_min(contrib: jax.Array, dst: jax.Array, num_segments: int,
         return _ref.segment_min(contrib, dst, num_segments)
     return _gg.segment_reduce_pallas(
         contrib, dst, num_segments, combine="min",
-        block_e=block_e, block_r=block_r, interpret=_interpret(),
+        block_e=block_e, block_r=block_r, interpret=interpret_mode(),
     )
 
 
@@ -73,18 +67,6 @@ def segment_max(contrib: jax.Array, dst: jax.Array, num_segments: int,
         return _ref.segment_max(contrib, dst, num_segments)
     return _gg.segment_reduce_pallas(
         contrib, dst, num_segments, combine="max",
-        block_e=block_e, block_r=block_r, interpret=_interpret(),
+        block_e=block_e, block_r=block_r, interpret=interpret_mode(),
     )
 
-
-def compact(mask: jax.Array, values: jax.Array, capacity: int,
-            block: int = _compact.DEFAULT_BLOCK,
-            fill_index: int | None = None) -> tuple[jax.Array, jax.Array]:
-    """First-`capacity` set indices of mask ``[V]`` (ascending) and their
-    values ``[V]``, as ``([K], [K])`` with K = capacity."""
-    if mask.shape[0] >= (1 << 24):
-        return _ref.compact(mask, values, capacity, fill_index)
-    return _compact.compact_pallas(
-        mask, values, capacity, block=block,
-        interpret=_interpret(), fill_index=fill_index,
-    )

@@ -21,16 +21,3 @@ def segment_max(contrib: jax.Array, dst: jax.Array, num_segments: int) -> jax.Ar
     (-inf when empty)."""
     return jax.ops.segment_max(contrib, dst, num_segments=num_segments)
 
-
-def compact(mask: jax.Array, values: jax.Array, capacity: int,
-            fill_index: int | None = None) -> tuple[jax.Array, jax.Array]:
-    """First-`capacity` indices where mask ``[V]`` is set (ascending) and
-    their values ``[V]``, as ``([K], [K])`` with K = capacity.
-
-    Unused slots hold (fill_index, 0).  fill_index defaults to len(mask).
-    """
-    n = mask.shape[0]
-    fill = n if fill_index is None else fill_index
-    (idx,) = jnp.nonzero(mask, size=capacity, fill_value=fill)
-    vals = jnp.where(idx < n, values[jnp.minimum(idx, n - 1)], 0)
-    return idx.astype(jnp.int32), vals

@@ -53,8 +53,6 @@ class ClusterConfig:
     timeout_seconds: float = 180.0
     #: parent-side timeout for the whole launch (seconds)
     launch_timeout_seconds: float = 900.0
-    #: JAX platform forced into the server processes (None = inherit)
-    jax_platforms: Optional[str] = "cpu"
     #: what to do when a rank dies or is preempted mid-run (DESIGN.md §12):
     #: "fail" = raise ClusterFailure; "restart" = tear down, respawn the
     #: same N resuming from the latest checkpoint; "shrink" = respawn with
@@ -215,10 +213,6 @@ def _run_attempt(store_root: str, progs: list, cfg: ClusterConfig,
         transport_mod.create_ring_files(run_dir, n, cfg.ring_capacity)
 
     ctx = mp.get_context("spawn")
-    saved_env = {k: os.environ.get(k) for k in ("JAX_PLATFORMS",)}
-    if cfg.jax_platforms is not None:
-        # children inherit the parent env at spawn time; restored below
-        os.environ["JAX_PLATFORMS"] = cfg.jax_platforms
     procs, conns = [], []
     try:
         for rank in range(n):
@@ -231,11 +225,6 @@ def _run_attempt(store_root: str, progs: list, cfg: ClusterConfig,
             child_conn.close()
             procs.append(p)
             conns.append(parent_conn)
-        for k, v in saved_env.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
 
         pids = [p.pid for p in procs]
         deadline = time.monotonic() + cfg.launch_timeout_seconds
@@ -290,6 +279,53 @@ def _run_attempt(store_root: str, progs: list, cfg: ClusterConfig,
                          verified=True, final_servers=n)
 
 
+def _probe_devices(conn) -> None:
+    """Child-process body of :func:`local_devices`."""
+    import jax
+
+    conn.send((jax.default_backend(), jax.local_device_count()))
+    conn.close()
+
+
+def local_devices() -> tuple[str, int]:
+    """``(platform, local device count)`` that the ranks will see.
+
+    Ranks inherit this process's environment, so a ``JAX_PLATFORMS`` that
+    leaves out the TPU answers without starting JAX (``("cpu", 0)`` — the
+    count is not needed there).  Otherwise a short-lived spawned child
+    asks JAX and exits, releasing the chips: the parent itself never
+    initialises a backend, because a parent holding the chip would lock
+    every rank out of it."""
+    plats = os.environ.get("JAX_PLATFORMS", "")
+    if plats and "tpu" not in plats.split(","):
+        return plats.split(",")[0], 0
+    ctx = mp.get_context("spawn")
+    parent_conn, child_conn = ctx.Pipe(duplex=False)
+    p = ctx.Process(target=_probe_devices, args=(child_conn,),
+                    name="graphh-device-probe", daemon=True)
+    p.start()
+    child_conn.close()
+    try:
+        if not parent_conn.poll(300.0):
+            raise TimeoutError("device probe did not answer in 300 s")
+        return parent_conn.recv()
+    finally:
+        parent_conn.close()
+        _teardown([p])
+
+
+def check_ranks_fit(num_servers: int) -> None:
+    """Refuse, before any rank spawns, more ranks than local TPU chips:
+    one chip belongs to one process, so a surplus rank would fail or hang
+    waiting for a chip.  Other platforms are not limited."""
+    platform, count = local_devices()
+    if platform == "tpu" and num_servers > count:
+        raise ValueError(
+            f"{num_servers} cluster ranks but only {count} local TPU "
+            f"chip(s): each rank needs a chip of its own; use --servers "
+            f"<= {count}")
+
+
 def run_cluster(store_root: str, progs: list,
                 cfg: ClusterConfig = ClusterConfig(),
                 run_dir: Optional[str] = None,
@@ -313,7 +349,11 @@ def run_cluster(store_root: str, progs: list,
     ``N - dead`` servers, remapping the checkpointed assignment at the
     superstep boundary (elastic resize).  Each attempt gets a fresh
     rendezvous subdirectory — stale ring frames from a killed attempt
-    must never be replayed into the next."""
+    must never be replayed into the next.
+
+    On a TPU platform more ranks than local chips are refused with a
+    ``ValueError`` before anything is spawned (:func:`check_ranks_fit`)."""
+    check_ranks_fit(cfg.num_servers)
     base_dir = run_dir or tempfile.mkdtemp(prefix="graphh_cluster_")
     own_dir = run_dir is None
     acfg = cfg
@@ -388,9 +428,11 @@ def main(argv=None) -> ClusterResult:
     """CLI: build (or reuse) a tile store, run one app on an N-server
     cluster, print per-superstep wire bytes and per-rank reports."""
     from repro.core.apps import APPS
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.graph import build_store
     from repro.graphio.formats import TileStore
 
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--app", default="pagerank", choices=sorted(APPS))
     ap.add_argument("--graph", default="rmat",

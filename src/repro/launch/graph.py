@@ -22,6 +22,7 @@ from repro.core.apps import APPS
 from repro.core.engine import EngineConfig, OutOfCoreEngine
 from repro.graphio import spe, synth
 from repro.graphio.formats import TileStore
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def build_store(args) -> TileStore:
@@ -163,6 +164,7 @@ def main(argv=None):
     """Parse CLI flags, build/reuse a tile store, and run the selected app
     through the out-of-core engine (or hand off to the multi-process
     cluster driver when ``--cluster`` is set)."""
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--app", default="pagerank", choices=sorted(APPS))
     ap.add_argument("--graph", default="rmat",

@@ -111,18 +111,21 @@ class RooflineTerms:
 def roofline(flops: float, hbm_bytes: float, coll_bytes: float,
              n_chips: int, model_flops_total: float,
              links: int = 1, hbm_bytes_fused: float = None) -> RooflineTerms:
-    compute_s = flops / hw.PEAK_FLOPS_BF16
-    memory_s = hbm_bytes / hw.HBM_BW
+    """Roofline terms of one compiled program on ``n_chips`` chips of the
+    dry-run's production target, the v5e."""
+    c = hw.chip(hw.V5E)
+    compute_s = flops / c.peak_flops_bf16
+    memory_s = hbm_bytes / c.hbm_bw
     fused = hbm_bytes if hbm_bytes_fused is None else hbm_bytes_fused
-    memory_fused_s = fused / hw.HBM_BW
-    collective_s = coll_bytes / (hw.ICI_BW_PER_LINK * links)
+    memory_fused_s = fused / c.hbm_bw
+    collective_s = coll_bytes / (c.ici_bw_per_link * links)
     # bottleneck / MFU use the fused (TPU-fusion-granularity) memory bound;
     # the conservative bound is reported alongside.
     terms = {"compute": compute_s, "memory": memory_fused_s,
              "collective": collective_s}
     bottleneck = max(terms, key=terms.get)
     step = max(compute_s, memory_fused_s, collective_s)
-    mfu = (model_flops_total / (n_chips * hw.PEAK_FLOPS_BF16 * step)
+    mfu = (model_flops_total / (n_chips * c.peak_flops_bf16 * step)
            if step > 0 else 0.0)
     per_dev_model = model_flops_total / n_chips
     return RooflineTerms(

@@ -4,15 +4,17 @@ Picks ``(BE, BR, stack_size)`` for ``kernels/gab_fused.py`` per
 ``(combine, Q, edge_cap, row_cap)`` from a dry-run cost model instead of
 the historical hand-picked ``(512, 256)`` (DESIGN.md §14):
 
-  * **HBM traffic** — the kernel re-streams the whole edge list once per
-    row block (``src [Q,E]`` + ``dst`` + optional scale/add streams), plus
-    one read/write of the row-block arrays.  Larger ``BR`` → fewer row
-    blocks → fewer edge re-streams; this term drives ``BR`` toward the
-    tile's full row cap.
-  * **Compute** — per-monoid arithmetic intensity: the sum monoid is a
-    ``2·Q·E·R`` MXU contraction, min/max a ``~3·Q·E·R`` masked VPU
-    select+reduce (no MXU form), and the one-hot build costs ``E·R``
-    compares either way.
+  * **Visited edge blocks** — each row block streams only the edge
+    blocks holding its edges; on a dst-sorted tile consecutive row blocks
+    share at most one boundary block, so the kernel visits at most
+    ``n_eblocks + n_rblocks - 1`` (edge block, row block) pairs.
+  * **HBM traffic** — every visited pair streams one edge block
+    (``src [Q,BE]`` + ``dst`` + optional scale/add streams), plus one
+    read/write of the row-block arrays.
+  * **Compute** — per visited pair, per-monoid arithmetic intensity: the
+    sum monoid is a ``2·Q·BE·BR`` MXU contraction, min/max a
+    ``~3·Q·BE·BR`` masked VPU select+reduce (no MXU form), and the
+    one-hot build costs ``BE·BR`` compares either way.
   * **Overhead** — a per-grid-step cost (DMA issue + semaphore sync) that
     penalizes tiny ``BE``; this is what makes big edge blocks win once
     VMEM allows them.
@@ -25,11 +27,12 @@ the historical hand-picked ``(512, 256)`` (DESIGN.md §14):
 (``edges_per_s``) drops the overhead term — the gap between a measured
 run and that ceiling is what ``bench_kernel_fused`` reports per app.
 
-The bandwidth is the declared HBM figure on TPU and a measured host
-``memcpy`` figure everywhere else (interpret mode streams through host
-memory), so predicted times are honest on both substrates.  The pick
-itself is bandwidth-independent given the candidate order, so CPU and
-TPU choose the same blocks for the same shape.
+The chip constants come from ``roofline/hw.py`` by the default device's
+``device_kind`` (an unknown TPU raises); CPU interpret mode rehearses the
+v5e kernel and plans with the v5e constants.  The bandwidth is the
+published HBM figure on TPU and a measured host ``memcpy`` figure on the
+CPU (interpret mode streams through host memory), so predicted times are
+honest on both substrates.
 """
 from __future__ import annotations
 
@@ -73,18 +76,33 @@ def _roundup(x: int, m: int) -> int:
     return max(-(-x // m) * m, m)
 
 
+def target_chip() -> hw.ChipSpec:
+    """Constants of the chip the fused kernel runs on: the default
+    device's kind (``hw.chip`` raises for one it does not know), or the
+    v5e the kernel is written for when the CPU interprets it."""
+    import jax
+
+    dev = jax.devices()[0]
+    return hw.chip(hw.V5E if dev.platform == "cpu" else dev.device_kind)
+
+
+def vmem_budget() -> int:
+    """Bytes of VMEM a pick may plan for on the target chip."""
+    return int(_VMEM_FRACTION * target_chip().vmem_bytes)
+
+
 @functools.lru_cache(maxsize=1)
 def measured_bandwidth() -> float:
     """Effective stream bandwidth in bytes/s.
 
-    On TPU: the declared HBM figure.  Elsewhere (interpret mode) a tiny
-    host memcpy microbench — best of three copies of a 32 MB buffer —
-    since that is the memory the interpreted kernel actually streams.
+    On TPU: the chip's published HBM figure.  On the CPU (interpret mode)
+    a tiny host memcpy microbench — best of three copies of a 32 MB
+    buffer — since that is the memory the interpreted kernel streams.
     """
     import jax
 
-    if jax.default_backend() == "tpu":
-        return float(hw.HBM_BW)
+    if jax.default_backend() != "cpu":
+        return float(target_chip().hbm_bw)
     buf = np.ones(32 * 1024 * 1024 // 8, dtype=np.float64)
     best = float("inf")
     for _ in range(3):
@@ -117,27 +135,30 @@ def tile_cost(combine: str, q: int, edge_cap: int, row_cap: int,
               bandwidth: float | None = None) -> KernelChoice:
     """Model one (BE, BR) config for one tile shape; stack_size unset (0)."""
     bw = measured_bandwidth() if bandwidth is None else bandwidth
+    chip = target_chip()
     qp = _roundup(q, hw.SUBLANES)
     ep = _roundup(edge_cap, block_e)
     rp = _roundup(row_cap, block_r)
     n_rb = rp // block_r
     n_eb = ep // block_e
+    visited = min(n_eb * n_rb, n_eb + n_rb - 1)   # (edge, row) block pairs
 
-    pass_bytes = ep * (4 * qp + 4 * (_n_streams(q) - 1))
+    block_bytes = block_e * (4 * qp + 4 * (_n_streams(q) - 1))
     row_bytes = rp * qp * 4 * 4             # old+base in, new+upd out
-    hbm_bytes = n_rb * pass_bytes + row_bytes
+    hbm_bytes = visited * block_bytes + row_bytes
 
-    onehot_ops = ep * rp
+    pair = block_e * block_r
+    onehot_ops = visited * pair
     if combine == "sum":
-        flops = 2 * qp * ep * rp
+        flops = 2 * qp * visited * pair
         vpu_ops = onehot_ops
     else:
         flops = 0
-        vpu_ops = 3 * qp * ep * rp + onehot_ops
-    compute_s = flops / hw.PEAK_FLOPS_F32 + vpu_ops / hw.VPU_OPS
+        vpu_ops = 3 * qp * visited * pair + onehot_ops
+    compute_s = flops / chip.peak_flops_f32 + vpu_ops / chip.vpu_ops
 
     roofline_s = max(hbm_bytes / bw, compute_s)
-    overhead_s = n_rb * (n_eb + 1) * hw.GRID_STEP_OVERHEAD_S
+    overhead_s = (visited + n_rb) * hw.GRID_STEP_OVERHEAD_S
     predicted_s = roofline_s + overhead_s
     return KernelChoice(
         block_e=block_e, block_r=block_r, stack_size=0,
@@ -166,8 +187,8 @@ def pick_blocks(combine: str, q: int, edge_cap: int, row_cap: int,
     tie-breaking.  The static (512, 256) default is always a candidate
     when feasible, so the pick can never model-predict worse than it.
     """
-    budget = int(_VMEM_FRACTION * (hw.VMEM_BYTES if vmem_bytes is None
-                                   else vmem_bytes))
+    budget = (vmem_budget() if vmem_bytes is None
+              else int(_VMEM_FRACTION * vmem_bytes))
     be_cap = _roundup(edge_cap, 128)
     br_cap = _roundup(row_cap, 128)
     cands = []

@@ -420,3 +420,33 @@ def test_remap_assignment_shrink_and_grow():
     assert all(len(a) > 0 for a in grown)
     # deterministic
     assert remap_assignment(old, 2, edges) == shrunk
+
+
+def test_cluster_refuses_more_ranks_than_tpu_chips(monkeypatch):
+    """On a TPU platform one chip belongs to one process: surplus ranks are
+    refused with a clear error before any rank is spawned."""
+    from repro.launch import cluster
+
+    monkeypatch.setattr(cluster, "local_devices", lambda: ("tpu", 1))
+
+    def no_spawn(*a, **k):
+        raise AssertionError("a rank was spawned")
+
+    monkeypatch.setattr(cluster, "_run_attempt", no_spawn)
+    with pytest.raises(ValueError, match="only 1 local TPU chip"):
+        run_cluster("/nonexistent-store", [PageRank()],
+                    ClusterConfig(num_servers=2))
+
+
+def test_local_devices_needs_no_jax_off_tpu(monkeypatch):
+    """A JAX_PLATFORMS without the TPU (what ranks inherit here) answers
+    without spawning a probe, so CPU launches pay nothing for the check."""
+    from repro.launch import cluster
+
+    def no_probe(*a, **k):
+        raise AssertionError("probe process spawned")
+
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    monkeypatch.setattr(cluster.mp, "get_context", no_probe)
+    assert cluster.local_devices() == ("cpu", 0)
+    cluster.check_ranks_fit(8)

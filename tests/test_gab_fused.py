@@ -114,6 +114,7 @@ SPECS = {
     (513, 257, 5),      # one past a block boundary both axes
     (64, 16, 1),        # far below one block (1-D squeeze path)
     (2000, 300, 8),     # a full sublane of queries
+    (5000, 1500, 1),    # many row blocks: most edge blocks are skipped
 ])
 @pytest.mark.parametrize("spec_name", sorted(SPECS))
 def test_fused_matches_unfused_composition(E, row_cap, Q, spec_name):
@@ -126,11 +127,33 @@ def test_fused_matches_unfused_composition(E, row_cap, Q, spec_name):
         spec, jnp.asarray(sv), None if a is None else jnp.asarray(a),
         None if b is None else jnp.asarray(b), jnp.asarray(dst),
         jnp.asarray(old), None if base is None else jnp.asarray(base),
-        jnp.int32(num_rows), row_cap, block_e=blocks[0], block_r=blocks[1])
+        jnp.int32(num_rows), row_cap, block_e=blocks[0], block_r=blocks[1],
+        interpret=True)
     new_u, upd_u = _unfused(spec, sv, a, b, dst, old, base, num_rows,
                             row_cap, blocks)
     np.testing.assert_array_equal(np.asarray(new_f), new_u, err_msg=spec_name)
     np.testing.assert_array_equal(np.asarray(upd_f), upd_u, err_msg=spec_name)
+
+
+@pytest.mark.parametrize("spec_name", ["sum_affine", "min_edge"])
+def test_fused_unsorted_dst_matches_unfused(spec_name):
+    """The per-row-block edge-block bounds span every edge of the row
+    block, so unsorted dst ids (wide bounds) stay exact too."""
+    spec = SPECS[spec_name]
+    rng = np.random.default_rng(11)
+    sv, a, b, dst, old, base, num_rows = _random_tile(rng, 1500, 400, 2,
+                                                      spec)
+    dst = rng.permutation(dst)
+    blocks = (128, 128)
+    new_f, upd_f = gab_fused(
+        spec, jnp.asarray(sv), None if a is None else jnp.asarray(a),
+        None if b is None else jnp.asarray(b), jnp.asarray(dst),
+        jnp.asarray(old), None, jnp.int32(num_rows), 400,
+        block_e=blocks[0], block_r=blocks[1], interpret=True)
+    new_u, upd_u = _unfused(spec, sv, a, b, dst, old, None, num_rows, 400,
+                            blocks)
+    np.testing.assert_array_equal(np.asarray(new_f), new_u)
+    np.testing.assert_array_equal(np.asarray(upd_f), upd_u)
 
 
 def test_fused_default_damping_within_float_noise():
@@ -143,7 +166,7 @@ def test_fused_default_damping_within_float_noise():
     sv, a, b, dst, old, base, num_rows = _random_tile(rng, 900, 200, 4, spec)
     new_f, _ = gab_fused(spec, jnp.asarray(sv), jnp.asarray(a), None,
                          jnp.asarray(dst), jnp.asarray(old), None,
-                         jnp.int32(num_rows), 200)
+                         jnp.int32(num_rows), 200, interpret=True)
     from repro.kernels.gab_gather import DEFAULT_BLOCK_E, DEFAULT_BLOCK_R
     new_u, _ = _unfused(spec, sv, a, b, dst, old, base, num_rows, 200,
                         (DEFAULT_BLOCK_E, DEFAULT_BLOCK_R))
@@ -166,7 +189,7 @@ def test_fused_empty_edge_list(spec_name):
         None if a is None else jnp.asarray(a),
         None if b is None else jnp.asarray(b),
         jnp.zeros((0,), jnp.int32), jnp.asarray(old), None,
-        jnp.int32(row_cap), row_cap)
+        jnp.int32(row_cap), row_cap, interpret=True)
     if spec.apply in ("min", "max"):
         np.testing.assert_array_equal(np.asarray(new_f), old)
         assert not np.asarray(upd_f).any()
@@ -193,7 +216,7 @@ def test_fused_all_padding_edges(spec_name):
         spec, jnp.asarray(sv), None if a is None else jnp.asarray(a),
         None if b is None else jnp.asarray(b), jnp.asarray(dst),
         jnp.asarray(old), None if spec.base_aux is None
-        else jnp.asarray(base), jnp.int32(row_cap), row_cap)
+        else jnp.asarray(base), jnp.int32(row_cap), row_cap, interpret=True)
     new_u, upd_u = _unfused(spec, sv, a, b, dst, old,
                             base if spec.base_aux else None,
                             row_cap, row_cap, (256, 128))
@@ -306,7 +329,7 @@ def test_pick_blocks_deterministic_and_feasible():
     assert a.predicted_s > 0 and a.edges_per_s > 0
     assert a.bound in ("memory", "compute")
     assert kernel_tune.vmem_plan_bytes("sum", 1, a.block_e, a.block_r) \
-        <= kernel_tune._VMEM_FRACTION * kernel_tune.hw.VMEM_BYTES
+        <= kernel_tune.vmem_budget()
 
 
 def test_pick_blocks_never_model_worse_than_static():
@@ -322,7 +345,7 @@ def test_pick_blocks_never_model_worse_than_static():
                     bandwidth=50e9)
                 feasible = kernel_tune.vmem_plan_bytes(
                     combine, q, *kernel_tune.STATIC_BLOCKS) \
-                    <= kernel_tune._VMEM_FRACTION * kernel_tune.hw.VMEM_BYTES
+                    <= kernel_tune.vmem_budget()
                 if feasible:
                     assert pick.predicted_s <= static.predicted_s, \
                         (combine, q, ec, rc)
@@ -334,7 +357,7 @@ def test_pick_blocks_vmem_constrains_minmax_wide_q():
     s = kernel_tune.pick_blocks("sum", 32, 8192, 1024, bandwidth=100e9)
     m = kernel_tune.pick_blocks("min", 32, 8192, 1024, bandwidth=100e9)
     assert kernel_tune.vmem_plan_bytes("min", 32, m.block_e, m.block_r) \
-        <= kernel_tune._VMEM_FRACTION * kernel_tune.hw.VMEM_BYTES
+        <= kernel_tune.vmem_budget()
     assert m.block_e * m.block_r <= s.block_e * s.block_r
 
 
@@ -343,6 +366,22 @@ def test_pick_blocks_caps_at_tile_shape():
     so a tiny tile picks the minimum (128, 128)."""
     c = kernel_tune.pick_blocks("sum", 1, 100, 60, bandwidth=100e9)
     assert c.blocks == (128, 128)
+
+
+def test_target_chip_unknown_tpu_kind_raises(monkeypatch):
+    """The tuner plans with the default device's constants: an unknown TPU
+    kind is an error, never the v5e numbers."""
+    import jax
+
+    class _Dev:
+        platform = "tpu"
+        device_kind = "TPU v99"
+
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [_Dev()])
+    with pytest.raises(KeyError, match="no hardware constants"):
+        kernel_tune.target_chip()
+    with pytest.raises(KeyError):
+        kernel_tune.pick_blocks("sum", 1, 4096, 512, bandwidth=100e9)
 
 
 def test_stack_size_scales_inverse_with_tile_time():

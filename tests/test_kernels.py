@@ -64,31 +64,6 @@ def test_segment_sum_unsorted_ids():
                                rtol=1e-5, atol=1e-5)
 
 
-@given(st.integers(1, 3000), st.floats(0.0, 0.39), st.integers(0, 10**6))
-@settings(max_examples=25, deadline=None)
-def test_compact_property(n, p, seed):
-    rng = np.random.default_rng(seed)
-    mask = jnp.asarray(rng.random(n) < p)
-    vals = jnp.asarray(rng.normal(size=n).astype(np.float32))
-    K = max(int(np.ceil(0.4 * n)), 1)
-    gi, gv = ops.compact(mask, vals, K)
-    ri, rv = ref.compact(mask, vals, K)
-    assert bool(jnp.all(gi == ri))
-    np.testing.assert_allclose(np.asarray(gv), np.asarray(rv), rtol=1e-6)
-
-
-def test_compact_block_sizes():
-    rng = np.random.default_rng(1)
-    n = 2048
-    mask = jnp.asarray(rng.random(n) < 0.3)
-    vals = jnp.asarray(rng.normal(size=n).astype(np.float32))
-    K = 1024
-    ri, rv = ref.compact(mask, vals, K)
-    for block in (128, 256, 1024):
-        gi, gv = ops.compact(mask, vals, K, block=block)
-        assert bool(jnp.all(gi == ri)), block
-
-
 # ---------------------------------------------------------------------------
 # multi-query (contrib [E, Q]) parity — the one-hot matvec becomes a GEMM
 # ---------------------------------------------------------------------------
@@ -186,8 +161,7 @@ def test_segment_reduce_integer_exact_above_2p24(combine):
 
     The Pallas path casts to f32, which cannot represent odd integers
     above 2**24 — the gather wrapper now routes >=32-bit integer inputs to
-    the exact jnp reference (mirroring the compact kernel's magnitude
-    guard in ops.py) instead of silently rounding."""
+    the exact jnp reference instead of silently rounding."""
     big = 1 << 24
     c = jnp.asarray([big - 1, big, big + 1, big + 3, 1, 2], dtype=jnp.int32)
     d = jnp.asarray([0, 0, 1, 1, 2, 2], dtype=jnp.int32)

@@ -74,12 +74,19 @@ ENTRY %main (p: f32[1024]) -> f32[1024] {
 
 
 def test_roofline_terms_math():
-    t = ra.roofline(flops=hw.PEAK_FLOPS_BF16, hbm_bytes=hw.HBM_BW / 2,
-                    coll_bytes=0, n_chips=4, model_flops_total=hw.PEAK_FLOPS_BF16)
+    v5e = hw.chip(hw.V5E)
+    t = ra.roofline(flops=v5e.peak_flops_bf16, hbm_bytes=v5e.hbm_bw / 2,
+                    coll_bytes=0, n_chips=4,
+                    model_flops_total=v5e.peak_flops_bf16)
     assert t.compute_s == pytest.approx(1.0)
     assert t.memory_s == pytest.approx(0.5)
     assert t.bottleneck == "compute"
     assert t.mfu_bound == pytest.approx(0.25)   # model/(4 chips * peak * 1s)
+
+
+def test_unknown_device_kind_raises():
+    with pytest.raises(KeyError, match="no hardware constants"):
+        hw.chip("TPU v99")
 
 
 def test_model_flops():
