@@ -90,18 +90,6 @@ class CacheStats:
         tot = self.hits + self.misses
         return self.hits / tot if tot else 0.0
 
-    def as_dict(self) -> dict:
-        """Plain-dict snapshot (for logs/benchmark JSON)."""
-        return dict(
-            hits=self.hits, misses=self.misses, evictions=self.evictions,
-            promotions=self.promotions, demotions=self.demotions,
-            tier_hits=dict(self.tier_hits),
-            hit_ratio=self.hit_ratio, disk_bytes_read=self.disk_bytes_read,
-            decompress_seconds=self.decompress_seconds,
-            retier_seconds=self.retier_seconds,
-            disk_seconds=self.disk_seconds,
-        )
-
 
 @dataclasses.dataclass
 class CacheEntry:

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import comm
+from repro.core import comm, obs
 from repro.core.bloom import SourceBlockBitmap, BloomFilter
 from repro.core.cache import EdgeCache, auto_select_mode, DEFAULT_GAMMAS
 from repro.core.checkpoint import GraphCheckpointer
@@ -204,11 +204,16 @@ class SuperstepStats:
     vstate_load_bytes: int = 0      # compressed bytes faulted back in
     vstate_spill_bytes: int = 0     # compressed bytes written to the disk tier
     vstate_dirty_intervals: int = 0 # intervals written back (and broadcast)
-
-    @property
-    def stall_fraction(self) -> float:
-        """Fraction of this superstep's wall time blocked on tile I/O."""
-        return self.stall_seconds / self.seconds if self.seconds > 0 else 0.0
+    # --- the tile step's phases and traffic (core/obs.py; each seconds
+    # field is its graphh.tile.* span's host time): compute_seconds is
+    # dispatch + fetch + split and the few statements between them
+    dispatch_seconds: float = 0.0   # inputs built, tile step enqueued
+    fetch_seconds: float = 0.0      # results copied back to the host
+    split_seconds: float = 0.0      # updated rows picked out
+    h2d_bytes: int = 0              # host arrays handed to the device
+    d2h_bytes: int = 0              # device arrays fetched to the host
+    edges_real: int = 0             # real edges of the processed tiles
+    edges_padded: int = 0           # their padded edge slots
 
     @property
     def io_hidden_seconds(self) -> float:
@@ -552,19 +557,20 @@ class OutOfCoreEngine:
     # pipelined path (cfg.pipeline): prefetch thread + batched dispatch
     # ------------------------------------------------------------------
     def _run_tiles_pipelined(self, s, tids, prog, values_dev, aux_dev,
-                             filters, nv):
+                             filters, nv, tally):
         """Overlapped tile processing for one server (DESIGN.md §7).
 
         A background thread reads + decompresses up to ``prefetch_depth``
         tiles ahead through the server's EdgeCache while the consumer
         stacks ``stack_size`` tiles and dispatches them as one jitted
-        ``run_tile_stack`` call.  The consumer's queue-wait is the disk
-        stall the pipeline failed to hide — reported per superstep.
+        ``run_tile_stack`` call.  The consumer's queue-wait (its
+        ``graphh.tile.load`` span in ``tally``) is the disk stall the
+        pipeline failed to hide.
 
-        Returns ([indices], [values], [query masks], load_s, compute_s,
-        stall_s) with results identical to the serial per-tile loop: tiles
-        own disjoint row ranges and the per-tile math is the same jitted
-        gather/apply.  The query-mask list is empty for 1-D runs.
+        Returns ([indices], [values], [query masks], compute_s) with
+        results identical to the serial per-tile loop: tiles own disjoint
+        row ranges and the per-tile math is the same jitted gather/apply.
+        The query-mask list is empty for 1-D runs.
         """
         from repro.core.distributed import pad_stack_to
         from repro.core.gab import run_tile_stack
@@ -572,31 +578,36 @@ class OutOfCoreEngine:
 
         cfg = self.cfg
         if not tids:
-            return [], [], [], 0.0, 0.0, 0.0
+            return [], [], [], 0.0
         if self._ooc:
             # ooc-vstate: the prefetcher still overlaps edge-tile reads with
             # compute, but tiles dispatch one at a time through the sharded
             # step (stacking would need the full [V] array on device)
-            return self._run_tiles_pipelined_ooc(s, tids, prog, filters, nv)
+            return self._run_tiles_pipelined_ooc(s, tids, prog, filters, nv,
+                                                 tally)
         row_cap = self.plan.row_cap
         seg_impl, kblocks, stack_k = self.kernel_plan(prog)
-        load_s = comp_s = stall_s = 0.0
+        comp_s = 0.0
         masked_acc = upd_acc = None
         batch: list = []
 
         def flush():
             nonlocal comp_s, masked_acc, upd_acc, batch
-            stk = stack_tiles(batch, row_cap)
-            if len(batch) < stack_k:
-                stk = pad_stack_to(stk, stack_k)  # keep one compiled shape
             t0 = time.perf_counter()
-            new_masked, upd = run_tile_stack(
-                prog, values_dev, aux_dev, stk, row_cap, seg_impl, kblocks)
-            if masked_acc is None:
-                masked_acc, upd_acc = new_masked, upd
-            else:  # disjoint row ranges: set-where-updated merge is exact
-                masked_acc = jnp.where(upd, new_masked, masked_acc)
-                upd_acc = jnp.logical_or(upd_acc, upd)
+            with tally.dispatch:
+                stk = stack_tiles(batch, row_cap)
+                if len(batch) < stack_k:
+                    stk = pad_stack_to(stk, stack_k)  # one compiled shape
+                tally.sent(*(stk[k] for k in ("src", "dst_local", "val",
+                                              "row_start", "num_rows")))
+                new_masked, upd = run_tile_stack(
+                    prog, values_dev, aux_dev, stk, row_cap, seg_impl,
+                    kblocks)
+                if masked_acc is None:
+                    masked_acc, upd_acc = new_masked, upd
+                else:  # disjoint row ranges: set-where-updated is exact
+                    masked_acc = jnp.where(upd, new_masked, masked_acc)
+                    upd_acc = jnp.logical_or(upd_acc, upd)
             comp_s += time.perf_counter() - t0
             batch = []
 
@@ -605,16 +616,14 @@ class OutOfCoreEngine:
                                       workers=cfg.prefetch_workers)
         try:
             while True:
-                t0 = time.perf_counter()
-                try:
-                    tid, tile = next(it)
-                except StopIteration:
-                    break
-                wait = time.perf_counter() - t0
-                load_s += wait
-                stall_s += wait
-                if filters is not None and filters[tid] is None:
-                    filters[tid] = self._make_filter(tile, nv)
+                with tally.load:
+                    try:
+                        tid, tile = next(it)
+                    except StopIteration:
+                        break
+                    if filters is not None and filters[tid] is None:
+                        filters[tid] = self._make_filter(tile, nv)
+                tally.tiles(tile.meta.num_edges, tile.meta.edge_cap)
                 batch.append(tile)
                 if len(batch) == stack_k:
                     flush()
@@ -623,18 +632,23 @@ class OutOfCoreEngine:
         finally:
             it.close()
 
-        si, sv, sm = self._split_updates(
-            np.arange(values_dev.shape[0]), np.asarray(masked_acc),
-            np.asarray(upd_acc))
-        return [si], [sv], [] if sm is None else [sm], load_s, comp_s, stall_s
+        t0 = time.perf_counter()
+        with tally.fetch:
+            masked, upd = tally.to_host(masked_acc, upd_acc)
+        with tally.split:
+            si, sv, sm = self._split_updates(
+                np.arange(values_dev.shape[0]), masked, upd)
+        comp_s += time.perf_counter() - t0
+        return [si], [sv], [] if sm is None else [sm], comp_s
 
     # ------------------------------------------------------------------
     # stacked fast path (engine_mode="stacked"): device-resident tiles
     # ------------------------------------------------------------------
-    def _build_stacks(self, nv: int) -> None:
+    def _build_stacks(self, nv: int, tally: obs.Tally) -> None:
         """Build the per-server device-resident tile stacks for
         ``engine_mode="stacked"`` — up to ``device_budget_bytes`` of tiles
-        per server live on device; the rest stream per superstep."""
+        per server live on device; the rest stream per superstep.  The
+        bytes moved count in ``tally``."""
         from repro.core.tiles import stack_tiles
 
         budget = self.cfg.device_budget_bytes
@@ -646,13 +660,13 @@ class OutOfCoreEngine:
             self._streamed[s] = self.assignment[s][fit:]
             tiles = [self.caches[s].get(t) for t in resident]
             stk = stack_tiles(tiles, self.plan.row_cap)
-            self._stacks[s] = {
-                k: jnp.asarray(stk[k])
-                for k in ("src", "dst_local", "val", "row_start", "num_rows")
-            }
+            keys = ("src", "dst_local", "val", "row_start", "num_rows")
+            tally.sent(*(stk[k] for k in keys))
+            self._stacks[s] = {k: jnp.asarray(stk[k]) for k in keys}
 
-    def _build_merged(self, nv: int) -> None:
-        """engine_mode="merged" (§Perf It5): per-server fused edge lists."""
+    def _build_merged(self, nv: int, tally: obs.Tally) -> None:
+        """engine_mode="merged" (§Perf It5): per-server fused edge lists,
+        the bytes moved counted in ``tally``."""
         self._stacks = {}
         for s in self.exec_servers:
             self._streamed[s] = []
@@ -666,12 +680,11 @@ class OutOfCoreEngine:
                 from repro.core.tiles import tile_edge_values
                 vals.append(tile_edge_values(t)[:n])
                 owned[t.meta.row_start: t.meta.row_end] = True
-            self._stacks[s] = dict(
-                src=jnp.asarray(np.concatenate(srcs).astype(np.int32)),
-                dst=jnp.asarray(np.concatenate(dsts).astype(np.int32)),
-                val=jnp.asarray(np.concatenate(vals)),
-                owned=jnp.asarray(owned[:nv]),
-            )
+            host = dict(src=np.concatenate(srcs).astype(np.int32),
+                        dst=np.concatenate(dsts).astype(np.int32),
+                        val=np.concatenate(vals), owned=owned[:nv])
+            tally.sent(*host.values())
+            self._stacks[s] = {k: jnp.asarray(v) for k, v in host.items()}
 
     def _merged_step(self, prog, values_dev, aux_dev, m):
         from repro.core.gab import merged_server_step
@@ -782,13 +795,14 @@ class OutOfCoreEngine:
             frozenset(ids) | {int(self._iv_t2i[m.tile_id])})
         return ids, ptr, perm
 
-    def _ooc_tile_step(self, prog, tile, nv):
+    def _ooc_tile_dispatch(self, prog, tile, nv, tally):
         """One tile's Gather+Apply against the interval-sharded vertex
         state: materialize per-edge source inputs interval by interval,
         slice the dst rows from the tile's own interval block, dispatch the
-        jitted sharded step.  Returns the same (ids, values, query-mask)
-        update triple as the in-memory path — bit-identical (see
-        gab.tile_gather_apply_sharded)."""
+        jitted sharded step (the host arrays it hands over count in
+        ``tally``).  Returns (rows [row_cap] host ids, new, updated) like
+        ``gab.run_tile`` — valid rows bit-identical to the in-memory path
+        (see gab.tile_gather_apply_sharded)."""
         vstore = self.vstate
         m = tile.meta
         row_cap = self.plan.row_cap
@@ -818,12 +832,15 @@ class OutOfCoreEngine:
             buf[: m.num_rows] = vstore.get_block(name, ivd)[r0:r1]
             dst_aux[name] = buf
         seg_impl, kblocks, _ = self.kernel_plan(prog)
+        edge_val = tile_edge_values(tile)
+        tally.sent(*bufs.values(), edge_val, tile.dst_local, old,
+                   *dst_aux.values(), scalars=1)
         new, upd = run_tile_sharded(
             prog, bufs["value"], {k: bufs[k] for k in prog.src_aux},
-            tile_edge_values(tile), tile.dst_local, old, dst_aux,
+            edge_val, tile.dst_local, old, dst_aux,
             m.num_rows, row_cap, seg_impl, kblocks)
         rows = np.minimum(m.row_start + np.arange(row_cap), nv - 1)
-        return self._split_updates(rows, np.asarray(new), np.asarray(upd))
+        return rows, new, upd
 
     def _ooc_column(self, vstore: VertexStateStore, c: int) -> np.ndarray:
         """Assemble one query column of the sharded value array."""
@@ -831,9 +848,9 @@ class OutOfCoreEngine:
             [vstore.get_block("value", k)[:, c]
              for k in range(vstore.num_intervals)])
 
-    def _run_tiles_pipelined_ooc(self, s, tids, prog, filters, nv):
+    def _run_tiles_pipelined_ooc(self, s, tids, prog, filters, nv, tally):
         cfg = self.cfg
-        load_s = comp_s = stall_s = 0.0
+        comp_s = 0.0
         s_idx: list = []
         s_val: list = []
         s_msk: list = []
@@ -842,26 +859,30 @@ class OutOfCoreEngine:
                                       workers=cfg.prefetch_workers)
         try:
             while True:
+                with tally.load:
+                    try:
+                        tid, tile = next(it)
+                    except StopIteration:
+                        break
+                    if filters is not None and filters[tid] is None:
+                        filters[tid] = self._make_filter(tile, nv)
+                tally.tiles(tile.meta.num_edges, tile.meta.edge_cap)
                 t0 = time.perf_counter()
-                try:
-                    tid, tile = next(it)
-                except StopIteration:
-                    break
-                wait = time.perf_counter() - t0
-                load_s += wait
-                stall_s += wait
-                if filters is not None and filters[tid] is None:
-                    filters[tid] = self._make_filter(tile, nv)
-                t0 = time.perf_counter()
-                ri, rv, rm = self._ooc_tile_step(prog, tile, nv)
+                with tally.dispatch:
+                    rows, new, upd = self._ooc_tile_dispatch(prog, tile, nv,
+                                                             tally)
+                with tally.fetch:
+                    rows, new, upd = tally.to_host(rows, new, upd)
+                with tally.split:
+                    ri, rv, rm = self._split_updates(rows, new, upd)
+                    s_idx.append(ri)
+                    s_val.append(rv)
+                    if rm is not None:
+                        s_msk.append(rm)
                 comp_s += time.perf_counter() - t0
-                s_idx.append(ri)
-                s_val.append(rv)
-                if rm is not None:
-                    s_msk.append(rm)
         finally:
             it.close()
-        return s_idx, s_val, s_msk, load_s, comp_s, stall_s
+        return s_idx, s_val, s_msk, comp_s
 
     def _order_joint_residency(self, s: int, tids: list[int]) -> list[int]:
         """Interval-aware co-scheduling (DESIGN.md §10): greedily pick the
@@ -1231,9 +1252,14 @@ class EngineSession:
         """Execute exactly one superstep (compute → barrier → apply →
         retirement → drains → admissions) and return its stats.  Raises
         ``runtime.ft.Preempted`` after a preemption checkpoint when the
-        engine's guard latched a signal."""
+        engine's guard latched a signal.  The whole superstep is the span
+        ``graphh.superstep`` (core/obs.py), keyed by its index."""
         if self.finished:
             raise RuntimeError("session is finished — open a new one")
+        with obs.span(obs.SUPERSTEP, superstep=self.superstep):
+            return self._step()
+
+    def _step(self) -> SuperstepStats:
         eng = self.eng
         cfg = eng.cfg
         prog = self.prog
@@ -1255,9 +1281,12 @@ class EngineSession:
         # scheduled/queued admissions): no compute, but the barrier — and
         # in cluster mode the exchange carrying the control record — runs
         run_compute = not (multi_q and qa == 0)
-        values_dev = (None if (ooc or not run_compute)
-                      else jnp.asarray(self.values))
-        load_s = 0.0
+        tally = obs.Tally()
+        values_dev = None
+        if run_compute and not ooc:
+            with obs.span(obs.VALUES_PUT):
+                tally.sent(self.values)
+                values_dev = jnp.asarray(self.values)
         comp_s = 0.0
         stall_s = 0.0
         tiles_done = 0
@@ -1301,33 +1330,43 @@ class EngineSession:
             server_tiles = eng.assignment[s]
             if self.engine_mode in ("stacked", "merged") and not skip_on:
                 if eng._stacks is None:
-                    t0 = time.perf_counter()
-                    if self.engine_mode == "merged":
-                        eng._build_merged(nv)
-                    else:
-                        eng._build_stacks(nv)
-                    if building_filters:
-                        for st in eng.exec_servers:
-                            n_res = (len(eng.assignment[st])
-                                     - len(eng._streamed[st]))
-                            for tid in eng.assignment[st][:n_res]:
-                                if filters[tid] is None:
-                                    filters[tid] = eng._make_filter(
-                                        eng.caches[st].get(tid), nv)
-                    load_s += time.perf_counter() - t0
+                    with tally.load:
+                        if self.engine_mode == "merged":
+                            eng._build_merged(nv, tally)
+                        else:
+                            eng._build_stacks(nv, tally)
+                        if building_filters:
+                            for st in eng.exec_servers:
+                                n_res = (len(eng.assignment[st])
+                                         - len(eng._streamed[st]))
+                                for tid in eng.assignment[st][:n_res]:
+                                    if filters[tid] is None:
+                                        filters[tid] = eng._make_filter(
+                                            eng.caches[st].get(tid), nv)
+                resident = eng.assignment[s][:len(eng.assignment[s])
+                                             - len(eng._streamed[s])]
+                real = int(eng.plan.edges_per_tile[resident].sum())
+                # merged lists hold only real edges; stacks pad each tile
+                tally.tiles(real, real if self.engine_mode == "merged"
+                            else len(resident) * eng.plan.edge_cap)
                 t0 = time.perf_counter()
-                step_fn = (eng._merged_step if self.engine_mode == "merged"
-                           else eng._stack_step)
-                new_masked, upd = step_fn(prog, values_dev, self.aux_dev,
-                                          eng._stacks[s])
-                si, sv, sm = eng._split_updates(
-                    np.arange(nv), np.asarray(new_masked), np.asarray(upd))
+                with tally.dispatch:
+                    step_fn = (eng._merged_step
+                               if self.engine_mode == "merged"
+                               else eng._stack_step)
+                    new_masked, upd = step_fn(prog, values_dev, self.aux_dev,
+                                              eng._stacks[s])
+                with tally.fetch:
+                    new_masked, upd = tally.to_host(new_masked, upd)
+                with tally.split:
+                    si, sv, sm = eng._split_updates(np.arange(nv),
+                                                    new_masked, upd)
+                    s_idx.append(si)
+                    s_val.append(sv.astype(vdtype))
+                    if sm is not None:
+                        s_msk.append(sm)
                 comp_s += time.perf_counter() - t0
-                s_idx.append(si)
-                s_val.append(sv.astype(vdtype))
-                if sm is not None:
-                    s_msk.append(sm)
-                tiles_done += len(eng.assignment[s]) - len(eng._streamed[s])
+                tiles_done += len(resident)
                 server_tiles = eng._streamed[s]
 
             # Tile-skipping pre-pass: the filter set is fixed for the
@@ -1335,19 +1374,20 @@ class EngineSession:
             # front (and handed to the prefetcher in pipelined mode).
             if skip_on:
                 run_list = []
-                for tid in server_tiles:
-                    f = eng._filters[tid]
-                    # a stolen tile may not have a filter yet on this
-                    # server (cluster mode) — run it, never skip blind
-                    hit = f is None or (
-                        f.intersects(active_words)
-                        if cfg.skip_filter == "bitmap"
-                        else f.might_contain_any(self.updated_ids)
-                    )
-                    if hit:
-                        run_list.append(tid)
-                    else:
-                        tiles_skipped += 1
+                with obs.span(obs.SKIP):
+                    for tid in server_tiles:
+                        f = eng._filters[tid]
+                        # a stolen tile may not have a filter yet on this
+                        # server (cluster mode) — run it, never skip blind
+                        hit = f is None or (
+                            f.intersects(active_words)
+                            if cfg.skip_filter == "bitmap"
+                            else f.might_contain_any(self.updated_ids)
+                        )
+                        if hit:
+                            run_list.append(tid)
+                        else:
+                            tiles_skipped += 1
                 if cfg.debug_skip_log:
                     eng.skip_log.append(dict(
                         superstep=ss, server=s,
@@ -1363,49 +1403,53 @@ class EngineSession:
             elif cfg.cache_aware_order and len(run_list) > 1:
                 run_list = eng._order_cache_first(s, run_list)
 
+            # every wait for a tile blocks compute: serially the whole
+            # load, pipelined what the prefetcher failed to hide
+            loaded_before = tally.load.seconds
             if cfg.pipeline:
-                p_idx, p_val, p_msk, ld, cp, stl = eng._run_tiles_pipelined(
+                p_idx, p_val, p_msk, cp = eng._run_tiles_pipelined(
                     s, run_list, prog, values_dev, self.aux_dev,
-                    filters if building_filters else None, nv)
+                    filters if building_filters else None, nv, tally)
                 s_idx += p_idx
                 s_val += p_val
                 s_msk += p_msk
-                load_s += ld
                 comp_s += cp
-                stall_s += stl
                 tiles_done += len(run_list)
             else:
                 for tid in run_list:
-                    t0 = time.perf_counter()
-                    tile = eng.caches[s].get(tid)
-                    dt = time.perf_counter() - t0
-                    load_s += dt
-                    stall_s += dt   # serial: every load blocks compute
-
-                    if building_filters and filters[tid] is None:
-                        filters[tid] = eng._make_filter(tile, nv)
+                    with tally.load:
+                        tile = eng.caches[s].get(tid)
+                        if building_filters and filters[tid] is None:
+                            filters[tid] = eng._make_filter(tile, nv)
+                    tally.tiles(tile.meta.num_edges, tile.meta.edge_cap)
 
                     t0 = time.perf_counter()
-                    if ooc:
-                        ri, rv, rm = eng._ooc_tile_step(prog, tile, nv)
-                    else:
-                        seg_impl, kblocks, _ = eng.kernel_plan(prog)
-                        rows, new, upd = run_tile(
-                            prog, values_dev, self.aux_dev,
-                            (tile.src, tile.dst_local,
-                             tile_edge_values(tile)),
-                            tile.meta.row_start, tile.meta.num_rows,
-                            row_cap, seg_impl, kblocks,
-                        )
-                        ri, rv, rm = eng._split_updates(
-                            np.asarray(rows), np.asarray(new),
-                            np.asarray(upd))
+                    with tally.dispatch:
+                        if ooc:
+                            rows, new, upd = eng._ooc_tile_dispatch(
+                                prog, tile, nv, tally)
+                        else:
+                            seg_impl, kblocks, _ = eng.kernel_plan(prog)
+                            edge_val = tile_edge_values(tile)
+                            tally.sent(tile.src, tile.dst_local, edge_val,
+                                       scalars=2)
+                            rows, new, upd = run_tile(
+                                prog, values_dev, self.aux_dev,
+                                (tile.src, tile.dst_local, edge_val),
+                                tile.meta.row_start, tile.meta.num_rows,
+                                row_cap, seg_impl, kblocks,
+                            )
+                    with tally.fetch:
+                        rows, new, upd = tally.to_host(rows, new, upd)
+                    with tally.split:
+                        ri, rv, rm = eng._split_updates(rows, new, upd)
+                        s_idx.append(ri)
+                        s_val.append(rv)
+                        if rm is not None:
+                            s_msk.append(rm)
                     comp_s += time.perf_counter() - t0
-                    s_idx.append(ri)
-                    s_val.append(rv)
-                    if rm is not None:
-                        s_msk.append(rm)
                     tiles_done += 1
+            stall_s += tally.load.seconds - loaded_before
             si = np.concatenate(s_idx) if s_idx else np.zeros(0, np.int64)
             val_shape = (0, qa) if multi_q else (0,)
             sv = (np.concatenate(s_val) if s_val
@@ -1438,200 +1482,210 @@ class EngineSession:
             eng._filters = filters
             self.building_filters = False
 
-        # --- Broadcast (BSP barrier): measure payloads, apply updates ---
-        if eng.fault is not None:
-            eng.fault.check("barrier", ss)
-        raw_b = wire_b = 0
-        control = None
-        if eng.exchange is not None:
-            # cluster mode (DESIGN.md §11): ship this server's updates
-            # through the real transport, merge every peer's frame — the
-            # exchange IS the global barrier, and the byte counts are
-            # measured from the frames that actually travelled.  Rank 0
-            # collects the admission/drain record pre-exchange (it must
-            # ride its frame); every rank applies the record it reads
-            # back below, after natural retirement.
-            if eng.exchange.rank == 0:
-                control = self._collect_control(
-                    ss, qa, set(self.active_queries), set())
-            si, sv, sm = per_server_updates[0]
-            xr = eng.exchange.exchange(
-                idx=si, vals=sv, mask=sm, nv=nv,
-                splitter=eng._iv_splitter if ooc else None,
-                compute_seconds=comp_s, control=control)
-            control = xr.control
-            all_idx, all_val, all_msk = xr.idx, xr.vals, xr.mask
-            raw_b, wire_b = xr.raw_bytes, xr.wire_bytes
-            if xr.assignment is not None:
-                # cross-server tile stealing: every server derived the
-                # same new ownership from the same replicated timings
-                eng.assignment = [list(a) for a in xr.assignment]
-        else:
-            for k, s in enumerate(eng.exec_servers):
-                if not run_compute:
-                    break
-                si, sv, sm = per_server_updates[k]
-                if sample:
-                    if s in bcast_futures:
-                        rec = bcast_futures[s].result()
-                    else:
-                        rec = eng._measure_broadcast(si, sv, sm, nv, qa,
-                                                     vdtype)
-                    raw_b += rec.raw_bytes
-                    wire_b += rec.wire_bytes
-                else:
-                    pairs = int(sm.sum()) if sm is not None else len(si)
-                    n_eff = nv * qa
-                    est = comm.wire_bytes_estimate(
-                        n_eff, pairs / max(n_eff, 1),
-                        # 2-D sparse payloads pack (vertex, query) u32 pairs
-                        index_bytes=8 if sm is not None else 4)
-                    raw_b += est
-                    wire_b += int(est * eng._wire_ratio)
-            if sample and raw_b:
-                eng._wire_ratio = wire_b / raw_b
-            all_idx = (np.concatenate(upd_idx_parts) if upd_idx_parts
-                       else np.zeros(0, np.int64))
-            all_val = (np.concatenate(upd_val_parts) if upd_val_parts
-                       else np.zeros((0, qa) if multi_q else (0,), vdtype))
-            all_msk = None
+        with obs.span(obs.BARRIER):
+            # --- Broadcast (BSP barrier): measure payloads, apply updates ---
+            if eng.fault is not None:
+                eng.fault.check("barrier", ss)
+            raw_b = wire_b = 0
+            control = None
+            if eng.exchange is not None:
+                # cluster mode (DESIGN.md §11): ship this server's updates
+                # through the real transport, merge every peer's frame — the
+                # exchange IS the global barrier, and the byte counts are
+                # measured from the frames that actually travelled.  Rank 0
+                # collects the admission/drain record pre-exchange (it must
+                # ride its frame); every rank applies the record it reads
+                # back below, after natural retirement.
+                if eng.exchange.rank == 0:
+                    control = self._collect_control(
+                        ss, qa, set(self.active_queries), set())
+                si, sv, sm = per_server_updates[0]
+                xr = eng.exchange.exchange(
+                    idx=si, vals=sv, mask=sm, nv=nv,
+                    splitter=eng._iv_splitter if ooc else None,
+                    compute_seconds=comp_s, control=control)
+                control = xr.control
+                all_idx, all_val, all_msk = xr.idx, xr.vals, xr.mask
+                raw_b, wire_b = xr.raw_bytes, xr.wire_bytes
+                if xr.assignment is not None:
+                    # cross-server tile stealing: every server derived the
+                    # same new ownership from the same replicated timings
+                    eng.assignment = [list(a) for a in xr.assignment]
+            else:
+                with obs.span(obs.BARRIER_MEASURE):
+                    for k, s in enumerate(eng.exec_servers):
+                        if not run_compute:
+                            break
+                        si, sv, sm = per_server_updates[k]
+                        if sample:
+                            if s in bcast_futures:
+                                rec = bcast_futures[s].result()
+                            else:
+                                rec = eng._measure_broadcast(
+                                    si, sv, sm, nv, qa, vdtype)
+                            raw_b += rec.raw_bytes
+                            wire_b += rec.wire_bytes
+                        else:
+                            pairs = (int(sm.sum()) if sm is not None
+                                     else len(si))
+                            n_eff = nv * qa
+                            est = comm.wire_bytes_estimate(
+                                n_eff, pairs / max(n_eff, 1),
+                                # 2-D sparse payloads pack (vertex,
+                                # query) u32 pairs
+                                index_bytes=8 if sm is not None else 4)
+                            raw_b += est
+                            wire_b += int(est * eng._wire_ratio)
+                if sample and raw_b:
+                    eng._wire_ratio = wire_b / raw_b
+                all_idx = (np.concatenate(upd_idx_parts) if upd_idx_parts
+                           else np.zeros(0, np.int64))
+                all_val = (np.concatenate(upd_val_parts) if upd_val_parts
+                           else np.zeros((0, qa) if multi_q else (0,), vdtype))
+                all_msk = None
+                if multi_q:
+                    all_msk = (np.concatenate(upd_msk_parts) if upd_msk_parts
+                               else np.zeros((0, qa), dtype=bool))
             if multi_q:
-                all_msk = (np.concatenate(upd_msk_parts) if upd_msk_parts
-                           else np.zeros((0, qa), dtype=bool))
-        if multi_q:
-            upd_per_q = all_msk.sum(axis=0)
-            updated_pairs = int(all_msk.sum())
-        else:
-            upd_per_q = None
-            updated_pairs = int(len(all_idx))
-        dirty_ivs = 0
-        if ooc:
-            # dirty-interval writeback (DESIGN.md §10): load only the
-            # interval blocks that received updates, apply in place,
-            # write back dirty — clean intervals are never touched.
-            if len(all_idx):
-                ivs = vstore.interval_of(all_idx)
-                for iv in np.unique(ivs):
-                    ksel = ivs == iv
-                    lo, _hi = vstore.interval_range(int(iv))
-                    blk = vstore.get_block("value", int(iv)).copy()
-                    loc = all_idx[ksel] - lo
-                    if multi_q:
-                        # per-cell application: a row touched by query A
-                        # must not clobber query B's untouched column
-                        cur = blk[loc]
-                        msk = all_msk[ksel]
-                        cur[msk] = all_val[ksel][msk]
-                        blk[loc] = cur
-                    else:
-                        blk[loc] = all_val[ksel]
-                    vstore.write_block("value", int(iv), blk)
-                    dirty_ivs += 1
-        elif multi_q:
-            # per-cell application: a row touched by query A must not
-            # clobber query B's column with a masked zero / sub-tol value
-            cur = self.values[all_idx]
-            cur[all_msk] = all_val[all_msk]
-            self.values[all_idx] = cur
-        else:
-            self.values[all_idx] = all_val
-        self.updated_ids = all_idx
-
-        # Re-tier at the barrier: off the tile hot path, after this
-        # superstep's access pattern has updated the per-tile counters.
-        if cfg.cache_policy != "lru":
-            for c in eng.caches.values():
-                c.maintain()
-
-        cache_stats = eng._agg_cache_stats()
-        io_busy = cache_stats["io_seconds"] - eng._io_busy_cum
-        eng._io_busy_cum = cache_stats["io_seconds"]
-        promo = cache_stats["promotions"] - eng._promo_cum
-        demo = cache_stats["demotions"] - eng._demo_cum
-        eng._promo_cum = cache_stats["promotions"]
-        eng._demo_cum = cache_stats["demotions"]
-        # the cache counter is cumulative over the run; the stat is the
-        # per-superstep delta (like io_busy/promotions above)
-        disk_b = cache_stats["disk_bytes_read"] - eng._disk_cum
-        eng._disk_cum = cache_stats["disk_bytes_read"]
-        vs_faults = vs_load = vs_spill = 0
-        if ooc:
-            vst = vstore.stats
-            vs_faults = vst.faults - eng._vs_faults_cum
-            vs_load = vst.load_bytes - eng._vs_load_cum
-            vs_spill = vst.spill_bytes - eng._vs_spill_cum
-            eng._vs_faults_cum = vst.faults
-            eng._vs_load_cum = vst.load_bytes
-            eng._vs_spill_cum = vst.spill_bytes
-
-        # --- barrier bookkeeping: natural retirement → drains → admissions
-        # (the same order in every execution mode — see class docstring).
-        retired: tuple = ()
-        drained: tuple = ()
-        admitted: tuple = ()
-        upd_map: dict = {}
-        ctl_pending = 0
-        if multi_q:
-            upd_map = {int(g): int(n)
-                       for g, n in zip(self.active_q, upd_per_q)}
-            done = np.nonzero(upd_per_q == 0)[0]
-            retired = tuple(int(self.active_q[c]) for c in done)
-            if eng.exchange is None:
-                # classic mode collects post-retirement: a slot freed at
-                # this barrier refills at this same barrier
-                control = self._collect_control(
-                    ss, qa - len(done), set(self.active_queries),
-                    set(retired))
-            ctl_admit, ctl_drain, ctl_pending = comm.unpack_admissions(
-                control)
-            drained = tuple(g for g in ctl_drain
-                            if g in set(self.active_queries)
-                            and g not in set(retired))
-            freeze = sorted(set(int(c) for c in done)
-                            | {int(np.nonzero(self.active_q == g)[0][0])
-                               for g in drained})
-            if freeze:
-                keep = np.ones(qa, dtype=bool)
-                keep[freeze] = False
-                done_set = set(int(c) for c in done)
+                upd_per_q = all_msk.sum(axis=0)
+                updated_pairs = int(all_msk.sum())
+            else:
+                upd_per_q = None
+                updated_pairs = int(len(all_idx))
+            with obs.span(obs.BARRIER_APPLY):
+                dirty_ivs = 0
                 if ooc:
-                    for c in freeze:
-                        gq = int(self.active_q[c])
-                        self.final_values[:, gq] = eng._ooc_column(vstore, c)
-                        if c in done_set:
-                            self.per_query_ss[gq] = (
-                                ss + 1 - int(self.admitted_at[gq]))
-                    q_names = [n for n in vstore.names()
-                               if vstore.spec(n)[1] == (qa,)]
-                    vstore.compact_columns(q_names, keep)
+                    # dirty-interval writeback (DESIGN.md §10): load only the
+                    # interval blocks that received updates, apply in place,
+                    # write back dirty — clean intervals are never touched.
+                    if len(all_idx):
+                        ivs = vstore.interval_of(all_idx)
+                        for iv in np.unique(ivs):
+                            ksel = ivs == iv
+                            lo, _hi = vstore.interval_range(int(iv))
+                            blk = vstore.get_block("value", int(iv)).copy()
+                            loc = all_idx[ksel] - lo
+                            if multi_q:
+                                # per-cell application: a row touched by
+                                # query A must not clobber query B's
+                                # untouched column
+                                cur = blk[loc]
+                                msk = all_msk[ksel]
+                                cur[msk] = all_val[ksel][msk]
+                                blk[loc] = cur
+                            else:
+                                blk[loc] = all_val[ksel]
+                            vstore.write_block("value", int(iv), blk)
+                            dirty_ivs += 1
+                elif multi_q:
+                    # per-cell application: a row touched by query A must
+                    # not clobber query B's column with a masked zero /
+                    # sub-tol value
+                    cur = self.values[all_idx]
+                    cur[all_msk] = all_val[all_msk]
+                    self.values[all_idx] = cur
                 else:
-                    for c in freeze:
-                        gq = int(self.active_q[c])
-                        self.final_values[:, gq] = self.values[:, c]
-                        if c in done_set:
-                            self.per_query_ss[gq] = (
-                                ss + 1 - int(self.admitted_at[gq]))
-                    self.values = np.ascontiguousarray(
-                        self.values[:, keep])
-                    for k in list(self.aux_np):
-                        a = self.aux_np[k]
-                        if a.ndim == 2 and a.shape[1] == qa:  # per-query
-                            self.aux_np[k] = np.ascontiguousarray(
-                                a[:, keep])
-                            self.aux_dev[k] = jnp.asarray(self.aux_np[k])
-                self.active_q = self.active_q[keep]
-            if ctl_admit:
-                self._apply_admissions(ctl_admit, ss)
-                admitted = tuple(int(g) for g, _ in ctl_admit)
-                self._force_full = True
-        # every rank drops the plan entries that fired at this barrier
-        # (peers never fire them, but must agree the backlog shrank)
-        self._plan_pending = [e for e in self._plan_pending if e[0] > ss]
+                    self.values[all_idx] = all_val
+                self.updated_ids = all_idx
+
+            with obs.span(obs.BARRIER_CACHE):
+                # Re-tier at the barrier: off the tile hot path, after this
+                # superstep's access pattern has updated the per-tile counters.
+                if cfg.cache_policy != "lru":
+                    for c in eng.caches.values():
+                        c.maintain()
+
+                cache_stats = eng._agg_cache_stats()
+                io_busy = cache_stats["io_seconds"] - eng._io_busy_cum
+                eng._io_busy_cum = cache_stats["io_seconds"]
+                promo = cache_stats["promotions"] - eng._promo_cum
+                demo = cache_stats["demotions"] - eng._demo_cum
+                eng._promo_cum = cache_stats["promotions"]
+                eng._demo_cum = cache_stats["demotions"]
+                # the cache counter is cumulative over the run; the stat is the
+                # per-superstep delta (like io_busy/promotions above)
+                disk_b = cache_stats["disk_bytes_read"] - eng._disk_cum
+                eng._disk_cum = cache_stats["disk_bytes_read"]
+                vs_faults = vs_load = vs_spill = 0
+                if ooc:
+                    vst = vstore.stats
+                    vs_faults = vst.faults - eng._vs_faults_cum
+                    vs_load = vst.load_bytes - eng._vs_load_cum
+                    vs_spill = vst.spill_bytes - eng._vs_spill_cum
+                    eng._vs_faults_cum = vst.faults
+                    eng._vs_load_cum = vst.load_bytes
+                    eng._vs_spill_cum = vst.spill_bytes
+
+            # --- barrier bookkeeping: natural retirement → drains →
+            # admissions (the same order in every execution mode — see
+            # class docstring).
+            retired: tuple = ()
+            drained: tuple = ()
+            admitted: tuple = ()
+            upd_map: dict = {}
+            ctl_pending = 0
+            if multi_q:
+                upd_map = {int(g): int(n)
+                           for g, n in zip(self.active_q, upd_per_q)}
+                done = np.nonzero(upd_per_q == 0)[0]
+                retired = tuple(int(self.active_q[c]) for c in done)
+                if eng.exchange is None:
+                    # classic mode collects post-retirement: a slot freed at
+                    # this barrier refills at this same barrier
+                    control = self._collect_control(
+                        ss, qa - len(done), set(self.active_queries),
+                        set(retired))
+                ctl_admit, ctl_drain, ctl_pending = comm.unpack_admissions(
+                    control)
+                drained = tuple(g for g in ctl_drain
+                                if g in set(self.active_queries)
+                                and g not in set(retired))
+                freeze = sorted(set(int(c) for c in done)
+                                | {int(np.nonzero(self.active_q == g)[0][0])
+                                   for g in drained})
+                if freeze:
+                    keep = np.ones(qa, dtype=bool)
+                    keep[freeze] = False
+                    done_set = set(int(c) for c in done)
+                    if ooc:
+                        for c in freeze:
+                            gq = int(self.active_q[c])
+                            self.final_values[:, gq] = eng._ooc_column(
+                                vstore, c)
+                            if c in done_set:
+                                self.per_query_ss[gq] = (
+                                    ss + 1 - int(self.admitted_at[gq]))
+                        q_names = [n for n in vstore.names()
+                                   if vstore.spec(n)[1] == (qa,)]
+                        vstore.compact_columns(q_names, keep)
+                    else:
+                        for c in freeze:
+                            gq = int(self.active_q[c])
+                            self.final_values[:, gq] = self.values[:, c]
+                            if c in done_set:
+                                self.per_query_ss[gq] = (
+                                    ss + 1 - int(self.admitted_at[gq]))
+                        self.values = np.ascontiguousarray(
+                            self.values[:, keep])
+                        for k in list(self.aux_np):
+                            a = self.aux_np[k]
+                            if a.ndim == 2 and a.shape[1] == qa:  # per-query
+                                self.aux_np[k] = np.ascontiguousarray(
+                                    a[:, keep])
+                                tally.sent(self.aux_np[k])
+                                self.aux_dev[k] = jnp.asarray(self.aux_np[k])
+                    self.active_q = self.active_q[keep]
+                if ctl_admit:
+                    self._apply_admissions(ctl_admit, ss, tally)
+                    admitted = tuple(int(g) for g, _ in ctl_admit)
+                    self._force_full = True
+            # every rank drops the plan entries that fired at this barrier
+            # (peers never fire them, but must agree the backlog shrank)
+            self._plan_pending = [e for e in self._plan_pending if e[0] > ss]
 
         stats = SuperstepStats(
             superstep=ss,
             seconds=time.perf_counter() - t_start,
-            load_seconds=load_s,
             compute_seconds=comp_s,
             updated_vertices=int(len(all_idx)),
             density=float(len(all_idx)) / max(nv, 1),
@@ -1657,6 +1711,7 @@ class EngineSession:
             vstate_load_bytes=vs_load,
             vstate_spill_bytes=vs_spill,
             vstate_dirty_intervals=dirty_ivs,
+            **tally.stats(),
         )
         self.history.append(stats)
         self.converged = (len(self.active_q) == 0 if multi_q
@@ -1759,7 +1814,8 @@ class EngineSession:
             return comm.pack_admissions(admit, drains,
                                         len(self._admit_queue))
 
-    def _apply_admissions(self, admit: list, ss: int) -> None:
+    def _apply_admissions(self, admit: list, ss: int,
+                          tally: obs.Tally) -> None:
         """Splice freshly admitted query columns into the live state — the
         inverse of retirement's compaction.  Initial column state comes
         from ``prog.with_queries(seeds).init`` (column math is independent
@@ -1767,7 +1823,7 @@ class EngineSession:
         fresh single-query run); per-query aux arrays ([V, q_new]) splice
         alongside, shared aux is untouched.  Deterministic given the
         control record, so every cluster rank converges to identical
-        state."""
+        state.  Aux arrays sent to the device count in ``tally``."""
         eng = self.eng
         nv = self.nv
         gqs = [int(g) for g, _ in admit]
@@ -1808,6 +1864,7 @@ class EngineSession:
             for k, arr in per_q_aux.items():
                 self.aux_np[k] = np.ascontiguousarray(
                     np.concatenate([self.aux_np[k], arr], axis=1))
+                tally.sent(self.aux_np[k])
                 self.aux_dev[k] = jnp.asarray(self.aux_np[k])
         self.active_q = np.concatenate(
             [self.active_q, np.asarray(gqs, dtype=self.active_q.dtype)])

@@ -226,34 +226,46 @@ def tile_gather_apply(
     seg_impl="pallas_fused" runs gather/combine/apply/mask as one fused
     Pallas kernel (DESIGN.md §14); ``blocks`` carries the autotuned
     ``(BE, BR)`` to either Pallas path.
+
+    The operations carry the named scopes ``graphh.gather`` (source
+    reads and per-edge messages), ``graphh.combine`` (the reduction into
+    rows) and ``graphh.apply`` (row reads, apply, update mask).  The fused
+    kernel reduces and applies in one, under ``graphh.combine``.
     """
     nv = values.shape[0]
-    src_vals = jnp.take(values, src, axis=0)
-    src_aux = {k: jnp.take(aux[k], src, axis=0) for k in prog.src_aux}
+    with jax.named_scope("graphh.gather"):
+        src_vals = jnp.take(values, src, axis=0)
+        src_aux = {k: jnp.take(aux[k], src, axis=0) for k in prog.src_aux}
     local_rows = jnp.arange(row_cap, dtype=jnp.int32)
     rows = jnp.minimum(row_start + local_rows, nv - 1)
 
     fs = prog.fused_spec() if seg_impl == "pallas_fused" else None
     if fs is not None:
-        old = jnp.take(values, rows, axis=0)
-        dst_aux = {k: jnp.take(aux[k], rows, axis=0) for k in prog.dst_aux}
-        new, updated = _fused_tile(prog, fs, src_vals, src_aux, edge_val,
-                                   dst_local, old, dst_aux, num_rows,
-                                   row_cap, blocks)
+        with jax.named_scope("graphh.apply"):
+            old = jnp.take(values, rows, axis=0)
+            dst_aux = {k: jnp.take(aux[k], rows, axis=0)
+                       for k in prog.dst_aux}
+        with jax.named_scope("graphh.combine"):
+            new, updated = _fused_tile(prog, fs, src_vals, src_aux, edge_val,
+                                       dst_local, old, dst_aux, num_rows,
+                                       row_cap, blocks)
         return rows, new, updated
 
-    contrib = prog.gather(src_vals, edge_val, src_aux)
-    accum = segment_reduce(
-        contrib, dst_local, row_cap + 1, prog.combine,
-        impl=_unfused_impl(seg_impl), blocks=blocks,
-    )[:row_cap]
+    with jax.named_scope("graphh.gather"):
+        contrib = prog.gather(src_vals, edge_val, src_aux)
+    with jax.named_scope("graphh.combine"):
+        accum = segment_reduce(
+            contrib, dst_local, row_cap + 1, prog.combine,
+            impl=_unfused_impl(seg_impl), blocks=blocks,
+        )[:row_cap]
 
-    old = jnp.take(values, rows, axis=0)
-    dst_aux = {k: jnp.take(aux[k], rows, axis=0) for k in prog.dst_aux}
-    new = prog.apply(old, accum, dst_aux)
-    valid = _bcast_rows(local_rows < num_rows, new)
-    new = jnp.where(valid, new, old)
-    updated = jnp.logical_and(valid, prog.updated_mask(old, new))
+    with jax.named_scope("graphh.apply"):
+        old = jnp.take(values, rows, axis=0)
+        dst_aux = {k: jnp.take(aux[k], rows, axis=0) for k in prog.dst_aux}
+        new = prog.apply(old, accum, dst_aux)
+        valid = _bcast_rows(local_rows < num_rows, new)
+        new = jnp.where(valid, new, old)
+        updated = jnp.logical_and(valid, prog.updated_mask(old, new))
     return rows, new, updated
 
 

@@ -471,6 +471,15 @@ def main(argv=None):
           f"mode={eng.cache_mode}, "
           f"disk-stall {res.disk_stall_fraction()*100:.0f}% of wall time"
           f"{' (pipelined)' if args.pipeline else ''}")
+    n = len(res.history)
+    h2d = sum(x.h2d_bytes for x in res.history) / n
+    d2h = sum(x.d2h_bytes for x in res.history) / n
+    real = sum(x.edges_real for x in res.history)
+    padded = sum(x.edges_padded for x in res.history)
+    fill = f"{100 * real / padded:.1f}%" if padded else "n/a (no tile ran)"
+    print(f"  host->device {h2d / 1e9:.3g} GB/superstep, device->host "
+          f"{d2h / 1e9:.3g} GB/superstep, edge fill {fill} "
+          f"({real} real edges in {padded} padded slots)")
     if args.vertex_memory_budget is not None:
         vs = eng.vstate.stats
         faults = sum(x.vstate_faults for x in res.history)
