@@ -26,7 +26,8 @@ from repro.core import comm, obs
 from repro.core.bloom import SourceBlockBitmap, BloomFilter
 from repro.core.cache import EdgeCache, auto_select_mode, DEFAULT_GAMMAS
 from repro.core.checkpoint import GraphCheckpointer
-from repro.core.gab import VertexProgram, run_tile, run_tile_sharded
+from repro.core.gab import (VertexProgram, run_tile, run_tile_sharded,
+                            run_tile_stack)
 from repro.core.partition import (assign_tiles, assign_tiles_balanced,
                                   plan_intervals)
 from repro.core.tiles import compute_source_footprint, tile_edge_values
@@ -82,8 +83,13 @@ class EngineConfig:
     # "stacked": device-resident stacked tiles, one scan per server (the
     #            HBM tier of the cache hierarchy; falls back to tiled for
     #            tiles beyond device_budget_bytes or when skipping is on)
-    engine_mode: str = "tiled"
-    device_budget_bytes: int = 1 << 30      # per server, for "stacked"
+    # "merged": per-server fused edge lists of real edges, device-resident
+    # "auto": "stacked" when every executed server's padded tile share
+    #         and the superstep's vertex arrays fit device_budget_bytes,
+    #         vertex state is in memory and no tiles are stolen, else
+    #         "tiled" (select_engine_mode)
+    engine_mode: str = "auto"
+    device_budget_bytes: int = 1 << 30      # per server: resident tile bytes
     # wire accounting: "full" compresses every payload (measured bytes);
     # "sampled" compresses every 4th superstep and reuses the last ratio
     comm_accounting: str = "full"
@@ -102,7 +108,7 @@ class EngineConfig:
     # byte budget for the interval-sharded VertexStateStore's in-memory
     # tiers (hot ndarrays + warm compressed blobs); beyond it, interval
     # blocks spill to a disk tier.  None keeps the paper's fully-resident
-    # [V, Q] vertex arrays.  Forces engine_mode="tiled" (stacked/merged
+    # [V, Q] vertex arrays.  Runs every session tiled (stacked/merged
     # need the full value array on device).
     vertex_memory_budget: Optional[int] = None
     # source intervals K; 0 = auto (sized so ~4 value blocks fit the
@@ -214,6 +220,7 @@ class SuperstepStats:
     d2h_bytes: int = 0              # device arrays fetched to the host
     edges_real: int = 0             # real edges of the processed tiles
     edges_padded: int = 0           # their padded edge slots
+    tiles_resident: int = 0         # processed tiles held on the device
 
     @property
     def io_hidden_seconds(self) -> float:
@@ -259,6 +266,54 @@ class RunResult:
         return sum(h.stall_seconds for h in hs) / tot if tot > 0 else 0.0
 
 
+#: device bytes of one padded edge slot of a stacked tile: int32 source,
+#: int32 local destination, float32 edge value
+STACK_SLOT_BYTES = 12
+
+
+def resident_vertex_bytes(nv: int, row_cap: int, edge_cap: int,
+                          values: np.ndarray, aux: dict) -> int:
+    """Device bytes a resident superstep holds besides its stacks, for a
+    session's vertex arrays ``values`` ([V] or [V, Q]) and ``aux``: the
+    values and aux on the device (twice over, as a superstep's put overlaps
+    the last one's arrays), the scan's padded values, aux, outputs and
+    update flags, its [V] results, and one tile's gathered sources and
+    contributions."""
+    row = values.nbytes // nv                  # one vertex's values
+    flags = row // values.dtype.itemsize       # its update flags
+    aux_row = sum(a.nbytes for a in aux.values()) // nv
+    return (2 * nv * (row + aux_row)
+            + (nv + row_cap + 1) * (2 * row + aux_row + flags)
+            + nv * (row + flags)
+            + edge_cap * (2 * row + aux_row))
+
+
+def select_engine_mode(cfg: EngineConfig, edge_cap: int,
+                       share_tiles: list[int], vertex_bytes: int,
+                       steal: bool = False) -> str:
+    """The execution mode of a session: ``"tiled"`` whenever the vertex
+    state is out of core, else the mode ``cfg.engine_mode`` names, with
+    ``"auto"`` resolved from what the engine can observe.  It is
+    ``"stacked"`` (every tile resident on the device, one scan per server
+    per superstep) when each executed server's ``share_tiles[i]`` padded
+    tiles and the superstep's ``vertex_bytes`` (``resident_vertex_bytes``)
+    take at most ``cfg.device_budget_bytes`` and no tiles are stolen, else
+    ``"tiled"``.  Stores over the budget never go partly resident.
+    Stealing moves tiles between servers, so it refuses the modes that
+    pin tiles to a device."""
+    if cfg.vertex_memory_budget is not None:
+        return "tiled"
+    if steal and cfg.engine_mode not in ("tiled", "auto"):
+        raise ValueError("tile stealing requires engine_mode 'tiled' or "
+                         "'auto' (stacked/merged pin tiles to devices)")
+    if cfg.engine_mode != "auto":
+        return cfg.engine_mode
+    per_tile = edge_cap * STACK_SLOT_BYTES
+    fits = all(n * per_tile + vertex_bytes <= cfg.device_budget_bytes
+               for n in share_tiles)
+    return "stacked" if fits and not steal else "tiled"
+
+
 class OutOfCoreEngine:
     """The out-of-core superstep engine (see module docstring).
 
@@ -279,10 +334,6 @@ class OutOfCoreEngine:
         self.plan = store.load_plan()
         self.in_degree, self.out_degree = store.load_degrees()
         P, N = self.plan.num_tiles, config.num_servers
-        if config.balanced_assignment:
-            self.assignment = assign_tiles_balanced(self.plan.edges_per_tile, N)
-        else:
-            self.assignment = assign_tiles(P, N)
         # cluster mode: this process executes exactly one server's share
         if config.server_rank is not None:
             if not 0 <= config.server_rank < N:
@@ -295,6 +346,9 @@ class OutOfCoreEngine:
             raise ValueError(
                 "a ClusterExchange needs exactly one executed server per "
                 "process — set cfg.server_rank (or num_servers=1)")
+        self._adopt_assignment(
+            assign_tiles_balanced(self.plan.edges_per_tile, N)
+            if config.balanced_assignment else assign_tiles(P, N))
 
         # --- checkpointing + fault injection (DESIGN.md §12) ---
         #: per-process arm of cfg.fault_plan (None = no injection)
@@ -321,8 +375,7 @@ class OutOfCoreEngine:
             for s in self.exec_servers
         }
         self._filters: Optional[list] = None  # built during first superstep
-        self._stacks: Optional[dict] = None   # per-server device-resident tiles
-        self._stack_fn = None
+        self._merged_fn = None
         # fused-kernel autotuning (DESIGN.md §14): memoized KernelChoice per
         # (combine, Q); ``kernel_choice`` holds the last resolved pick for
         # stats/CLI reporting
@@ -415,11 +468,31 @@ class OutOfCoreEngine:
             return
         n = self.cfg.num_servers
         if len(saved) == n:
-            self.assignment = [list(map(int, a)) for a in saved]
+            self._adopt_assignment([list(map(int, a)) for a in saved])
         else:
-            self.assignment = remap_assignment(
+            self._adopt_assignment(remap_assignment(
                 [list(map(int, a)) for a in saved], n,
-                self.plan.edges_per_tile)
+                self.plan.edges_per_tile))
+
+    def _adopt_assignment(self, assignment: list[list[int]]) -> None:
+        """Take ``assignment`` as the per-server tile shares and drop
+        resident stacks built for other shares."""
+        self.assignment = assignment
+        #: per-server device-resident tiles, built at the first dense
+        #: superstep of a stacked or merged session
+        self._stacks: Optional[dict] = None
+
+    def resolve_mode(self, values: np.ndarray, aux: dict) -> str:
+        """The execution mode of a session over the vertex arrays
+        ``values`` ([V] or [V, Q]) and ``aux`` ([V] or [V, Q] each)
+        (``select_engine_mode``)."""
+        plan = self.plan
+        return select_engine_mode(
+            self.cfg, plan.edge_cap,
+            [len(self.assignment[s]) for s in self.exec_servers],
+            resident_vertex_bytes(plan.num_vertices, plan.row_cap,
+                                  plan.edge_cap, values, aux),
+            steal=getattr(self.exchange, "steal", False))
 
     def _save_final(self, values, aux_np, per_query_ss, converged,
                     supersteps: int) -> None:
@@ -573,7 +646,6 @@ class OutOfCoreEngine:
         The query-mask list is empty for 1-D runs.
         """
         from repro.core.distributed import pad_stack_to
-        from repro.core.gab import run_tile_stack
         from repro.core.tiles import stack_tiles
 
         cfg = self.cfg
@@ -652,7 +724,7 @@ class OutOfCoreEngine:
         from repro.core.tiles import stack_tiles
 
         budget = self.cfg.device_budget_bytes
-        per_tile = self.plan.edge_cap * 12  # src+dst+val
+        per_tile = self.plan.edge_cap * STACK_SLOT_BYTES
         self._stacks = {}
         for s in self.exec_servers:
             fit = max(1, budget // per_tile)
@@ -689,7 +761,7 @@ class OutOfCoreEngine:
     def _merged_step(self, prog, values_dev, aux_dev, m):
         from repro.core.gab import merged_server_step
 
-        if self._stack_fn is None:
+        if self._merged_fn is None:
             from functools import partial
 
             @partial(jax.jit, static_argnums=(0, 1, 2))
@@ -697,28 +769,17 @@ class OutOfCoreEngine:
                 return merged_server_step(p, values, aux, src, dst, val,
                                           owned, seg_impl, blocks)
 
-            self._stack_fn = fn
+            self._merged_fn = fn
         seg_impl, kblocks, _ = self.kernel_plan(prog)
-        return self._stack_fn(prog, seg_impl, kblocks, values_dev, aux_dev,
-                              m["src"], m["dst"], m["val"], m["owned"])
+        return self._merged_fn(prog, seg_impl, kblocks, values_dev, aux_dev,
+                               m["src"], m["dst"], m["val"], m["owned"])
 
     def _stack_step(self, prog, values_dev, aux_dev, stack):
-        from repro.core.gab import stacked_tiles_step
-
-        if self._stack_fn is None:
-            from functools import partial
-
-            row_cap = self.plan.row_cap
-
-            @partial(jax.jit, static_argnums=(0, 3, 4))
-            def fn(p, values, aux, seg_impl, blocks, stk):
-                return stacked_tiles_step(p, values, aux, stk, row_cap,
-                                          seg_impl, blocks)
-
-            self._stack_fn = fn
+        """One server's superstep over its resident stack: one device
+        program (``gab._jit_run_tile_stack``) scanning every tile."""
         seg_impl, kblocks, _ = self.kernel_plan(prog)
-        return self._stack_fn(prog, values_dev, aux_dev, seg_impl, kblocks,
-                              stack)
+        return run_tile_stack(prog, values_dev, aux_dev, stack,
+                              self.plan.row_cap, seg_impl, kblocks)
 
     # ------------------------------------------------------------------
     def _make_filter(self, tile, nv):
@@ -1144,9 +1205,10 @@ class EngineSession:
         # --- out-of-core vertex state (DESIGN.md §10): with a vertex
         # memory budget the [V(, Q)] arrays move into an interval-sharded
         # VertexStateStore and the full arrays are dropped.  stacked/
-        # merged need the full value array on device, so ooc forces tiled.
+        # merged need the full value array on device, so ooc runs tiled.
         self._ooc = engine._ooc = cfg.vertex_memory_budget is not None
-        self.engine_mode = "tiled" if self._ooc else cfg.engine_mode
+        #: the resolved execution mode: "tiled", "stacked" or "merged"
+        self.engine_mode = engine.resolve_mode(self.values, self.aux_np)
         if self._ooc:
             self.vstore = engine._build_vstate(self.values, self.aux_np)
             engine._vs_faults_cum = self.vstore.stats.faults
@@ -1348,7 +1410,8 @@ class EngineSession:
                 real = int(eng.plan.edges_per_tile[resident].sum())
                 # merged lists hold only real edges; stacks pad each tile
                 tally.tiles(real, real if self.engine_mode == "merged"
-                            else len(resident) * eng.plan.edge_cap)
+                            else len(resident) * eng.plan.edge_cap,
+                            resident=len(resident))
                 t0 = time.perf_counter()
                 with tally.dispatch:
                     step_fn = (eng._merged_step
@@ -1510,7 +1573,7 @@ class EngineSession:
                 if xr.assignment is not None:
                     # cross-server tile stealing: every server derived the
                     # same new ownership from the same replicated timings
-                    eng.assignment = [list(a) for a in xr.assignment]
+                    eng._adopt_assignment([list(a) for a in xr.assignment])
             else:
                 with obs.span(obs.BARRIER_MEASURE):
                     for k, s in enumerate(eng.exec_servers):

@@ -336,6 +336,10 @@ def stacked_tiles_step(
     per-tile update is a dynamic-slice read-modify-write on padded buffers
     rather than a scatter (§Perf It3: ~2x on the CPU engine; on TPU this is
     the difference between a DUS and a gather/scatter pair).
+
+    The scan body carries the named scopes of :func:`tile_gather_apply`:
+    ``graphh.gather``, ``graphh.combine`` and ``graphh.apply`` (which also
+    writes the tile's rows into the outputs).
     """
     nv = values.shape[0]
     pad = row_cap + 1
@@ -350,32 +354,42 @@ def stacked_tiles_step(
         row_start = tile["row_start"]
         num_rows = tile["num_rows"]
 
-        src_vals = jnp.take(values, tile["src"], axis=0)
-        src_aux = {k: jnp.take(aux[k], tile["src"], axis=0)
-                   for k in prog.src_aux}
-        old = _dslice(values_p, row_start, row_cap)
-        dst_aux = {k: _dslice(aux_p[k], row_start, row_cap)
-                   for k in prog.dst_aux}
+        with jax.named_scope("graphh.gather"):
+            src_vals = jnp.take(values, tile["src"], axis=0)
+            src_aux = {k: jnp.take(aux[k], tile["src"], axis=0)
+                       for k in prog.src_aux}
+        with jax.named_scope("graphh.apply"):
+            old = _dslice(values_p, row_start, row_cap)
+            dst_aux = {k: _dslice(aux_p[k], row_start, row_cap)
+                       for k in prog.dst_aux}
         if fs is not None:
-            new, updated = _fused_tile(
-                prog, fs, src_vals, src_aux, tile["val"], tile["dst_local"],
-                old, dst_aux, num_rows, row_cap, blocks)
+            with jax.named_scope("graphh.combine"):
+                new, updated = _fused_tile(
+                    prog, fs, src_vals, src_aux, tile["val"],
+                    tile["dst_local"], old, dst_aux, num_rows, row_cap,
+                    blocks)
         else:
-            contrib = prog.gather(src_vals, tile["val"], src_aux)
-            accum = segment_reduce(contrib, tile["dst_local"], row_cap + 1,
-                                   prog.combine, impl=_unfused_impl(seg_impl),
-                                   blocks=blocks)[:row_cap]
-            new = prog.apply(old, accum, dst_aux)
-            local = jnp.arange(row_cap, dtype=jnp.int32)
-            valid = _bcast_rows(local < num_rows, new)
-            new = jnp.where(valid, new, old)
-            updated = jnp.logical_and(valid, prog.updated_mask(old, new))
+            with jax.named_scope("graphh.gather"):
+                contrib = prog.gather(src_vals, tile["val"], src_aux)
+            with jax.named_scope("graphh.combine"):
+                accum = segment_reduce(
+                    contrib, tile["dst_local"], row_cap + 1, prog.combine,
+                    impl=_unfused_impl(seg_impl), blocks=blocks)[:row_cap]
+            with jax.named_scope("graphh.apply"):
+                new = prog.apply(old, accum, dst_aux)
+                local = jnp.arange(row_cap, dtype=jnp.int32)
+                valid = _bcast_rows(local < num_rows, new)
+                new = jnp.where(valid, new, old)
+                updated = jnp.logical_and(valid,
+                                          prog.updated_mask(old, new))
 
-        cur = _dslice(out_p, row_start, row_cap)
-        window = jnp.where(updated, new, cur)   # set-where-updated (overlap-safe)
-        out_p = _dupdate(out_p, window, row_start)
-        cur_u = _dslice(upd_p, row_start, row_cap)
-        upd_p = _dupdate(upd_p, cur_u | updated, row_start)
+        with jax.named_scope("graphh.apply"):
+            cur = _dslice(out_p, row_start, row_cap)
+            # set-where-updated (overlap-safe)
+            window = jnp.where(updated, new, cur)
+            out_p = _dupdate(out_p, window, row_start)
+            cur_u = _dslice(upd_p, row_start, row_cap)
+            upd_p = _dupdate(upd_p, cur_u | updated, row_start)
         return (out_p, upd_p), None
 
     delta0 = jnp.zeros((nv + pad,) + tail, values.dtype)
@@ -477,10 +491,10 @@ def run_tile_sharded(prog, src_vals, src_aux, edge_val, dst_local, old,
 
 
 # ---------------------------------------------------------------------------
-# Stacked-tile batch entry used by the pipelined engine: K prefetched tiles,
-# padded to a fixed stack size, dispatched as ONE jitted scan.  Amortizes
-# per-tile dispatch overhead; compilation is keyed by (K, edge_cap, row_cap),
-# so a fixed stack_size means a single compile for the whole run.
+# Stacked-tile entry, dispatched as ONE jitted scan: K prefetched tiles padded
+# to a fixed stack size (the pipelined engine), or a server's whole
+# device-resident stack (the stacked engine's superstep).  Compilation is
+# keyed by (K, edge_cap, row_cap), so each stack shape compiles once.
 # ---------------------------------------------------------------------------
 
 @partial(jax.jit, static_argnums=(0, 4, 5, 6))

@@ -80,7 +80,9 @@ class Tally:
     - ``h2d_bytes``: ``nbytes`` of every host array handed to the device;
     - ``d2h_bytes``: ``nbytes`` of every device array fetched to the host;
     - ``edges_real`` / ``edges_padded``: real edges and padded edge slots of
-      the tiles processed.
+      the tiles processed;
+    - ``tiles_resident``: processed tiles that ran from edges held on the
+      device across supersteps (resident stacks or merged edge lists).
     """
 
     def __init__(self):
@@ -92,6 +94,7 @@ class Tally:
         self.d2h_bytes = 0
         self.edges_real = 0
         self.edges_padded = 0
+        self.tiles_resident = 0
 
     def sent(self, *arrays, scalars: int = 0) -> None:
         """Count host ``arrays`` (and ``scalars`` int32 scalars) handed to
@@ -110,10 +113,12 @@ class Tally:
             out.append(np.asarray(a))
         return out
 
-    def tiles(self, real: int, padded: int) -> None:
-        """Count processed tiles' ``real`` edges and ``padded`` slots."""
+    def tiles(self, real: int, padded: int, resident: int = 0) -> None:
+        """Count processed tiles' ``real`` edges and ``padded`` slots, and
+        the ``resident`` tiles among them that the device already held."""
         self.edges_real += int(real)
         self.edges_padded += int(padded)
+        self.tiles_resident += int(resident)
 
     def stats(self) -> dict:
         """The accumulated numbers as ``SuperstepStats`` keyword args."""
@@ -123,4 +128,5 @@ class Tally:
             fetch_seconds=self.fetch.seconds,
             split_seconds=self.split.seconds,
             h2d_bytes=self.h2d_bytes, d2h_bytes=self.d2h_bytes,
-            edges_real=self.edges_real, edges_padded=self.edges_padded)
+            edges_real=self.edges_real, edges_padded=self.edges_padded,
+            tiles_resident=self.tiles_resident)

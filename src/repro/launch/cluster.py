@@ -46,7 +46,8 @@ class ClusterConfig:
     #: per-directed-channel ring capacity in bytes (shm transport)
     ring_capacity: int = 1 << 22
     #: cross-server tile stealing between supersteps (scheduler.
-    #: rebalance_assignment); requires engine_mode="tiled"
+    #: rebalance_assignment); the engine then runs "auto" tiled and
+    #: refuses "stacked"/"merged" (engine.select_engine_mode)
     steal: bool = False
     straggler_factor: float = 1.5
     #: per-superstep exchange timeout inside each server (seconds)
@@ -122,9 +123,6 @@ def _server_main(rank: int, store_root: str, cfg: ClusterConfig,
         ecfg = dataclasses.replace(
             cfg.engine, num_servers=cfg.num_servers, server_rank=rank,
             checkpoint_dir=None)
-        if cfg.steal and ecfg.engine_mode != "tiled":
-            raise ValueError("tile stealing requires engine_mode='tiled' "
-                             "(stacked/merged pin tiles to devices)")
         eng = OutOfCoreEngine(store, ecfg)
         transport = transport_mod.make_transport(
             cfg.transport, rank, cfg.num_servers, run_dir)

@@ -55,7 +55,7 @@ CASES = [
 
 MODES = {
     "serial": {},
-    "pipelined": dict(pipeline=True),
+    "pipelined": dict(engine_mode="tiled", pipeline=True),
     "ooc": dict(vertex_memory_budget=48 * 1024, num_intervals=4),
 }
 

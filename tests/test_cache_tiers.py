@@ -271,6 +271,8 @@ def _engine_run(store, prog, **kw):
     from repro.core.engine import EngineConfig, OutOfCoreEngine
 
     kw.setdefault("max_supersteps", 200)
+    # the per-tile paths, where the edge cache serves every superstep
+    kw.setdefault("engine_mode", "tiled")
     cfg = EngineConfig(num_servers=3, **kw)
     return OutOfCoreEngine(store, cfg).run(prog)
 
@@ -358,7 +360,7 @@ def test_second_run_stats_rebaselined(small_store):
     sizes = [store.tile_disk_bytes(t) for t in range(plan.num_tiles)]
     eng = OutOfCoreEngine(store, EngineConfig(
         num_servers=2, cache_capacity_bytes=sum(sizes) // 3, cache_mode=2,
-        tile_skipping=False, max_supersteps=3))
+        tile_skipping=False, max_supersteps=3, engine_mode="tiled"))
     eng.run(PageRank())
     # external cache traffic between the runs: clear + touch tiles directly
     for c in eng.caches.values():

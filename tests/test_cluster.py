@@ -141,7 +141,8 @@ def test_inprocess_cluster_ooc_vstate(stores):
 def test_inprocess_cluster_pipelined(stores):
     unweighted, _ = stores
     ref = _reference(unweighted, PageRank(), 2)
-    outs = _thread_cluster(unweighted, PageRank, 2, pipeline=True)
+    outs = _thread_cluster(unweighted, PageRank, 2, engine_mode="tiled",
+                           pipeline=True)
     assert np.array_equal(outs[0].values, ref.values)
 
 

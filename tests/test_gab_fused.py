@@ -271,8 +271,10 @@ def test_engine_autotuned_pipelined_bit_identical(small_store, app_name, mk):
     """Serial and pipelined fused execution agree byte for byte (Q=1 and a
     full Q=8 sublane)."""
     store, _, _ = small_store
-    v_serial, _ = _run(store, mk(), kernel_autotune=True)
-    v_pipe, _ = _run(store, mk(), kernel_autotune=True, pipeline=True)
+    v_serial, _ = _run(store, mk(), kernel_autotune=True,
+                       engine_mode="tiled")
+    v_pipe, _ = _run(store, mk(), kernel_autotune=True, engine_mode="tiled",
+                     pipeline=True)
     np.testing.assert_array_equal(v_serial, v_pipe, err_msg=app_name)
 
 

@@ -144,8 +144,9 @@ def test_q32_ppr_streams_tiles_once(small_store):
     seeds = tuple(int(v) for v in rng.choice(plan.num_vertices, 32,
                                              replace=False))
     # 1-byte cache: every tile visit is a real disk read, so disk_bytes_read
-    # counts tile streaming exactly
-    kw = dict(cache_capacity_bytes=1, tile_skipping=False)
+    # counts tile streaming exactly (per tile: resident tiles are read once)
+    kw = dict(cache_capacity_bytes=1, tile_skipping=False,
+              engine_mode="tiled")
     rb = run(store, PersonalizedPageRank(seeds=seeds), **kw)
     assert rb.converged
 

@@ -189,9 +189,10 @@ def test_run_tile_stack_matches_run_tile(small_store):
 # --------------------------- engine equivalence ----------------------------
 
 def _run(store, prog, pipeline, **kw):
+    # the per-tile paths, serial and pipelined, not the resident stacks
     cfg = EngineConfig(num_servers=3, max_supersteps=200, pipeline=pipeline,
                        prefetch_depth=3, prefetch_workers=2, stack_size=2,
-                       **kw)
+                       engine_mode="tiled", **kw)
     return OutOfCoreEngine(store, cfg).run(prog)
 
 
@@ -265,6 +266,7 @@ def test_pipelined_stack_size_one(small_store):
     store, plan, _ = small_store
     ser = _run(store, PageRank(update_tol=1e-10), pipeline=False)
     cfg = EngineConfig(num_servers=2, max_supersteps=200, pipeline=True,
-                       prefetch_depth=1, prefetch_workers=1, stack_size=1)
+                       prefetch_depth=1, prefetch_workers=1, stack_size=1,
+                       engine_mode="tiled")
     pip = OutOfCoreEngine(store, cfg).run(PageRank(update_tol=1e-10))
     assert np.array_equal(ser.values, pip.values)

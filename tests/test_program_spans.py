@@ -79,7 +79,8 @@ def test_a_traced_superstep_holds_every_serial_span_nested(store, arcs,
                                                           tmp_path):
     # per-vertex skip filters, and a root whose one out-neighbour has the
     # fewest out-edges: superstep 1 runs that neighbour's tiles, skips others
-    eng = OutOfCoreEngine(store, EngineConfig(block_shift=0))
+    eng = OutOfCoreEngine(store, EngineConfig(block_shift=0,
+                                              engine_mode="tiled"))
     src, dst = arcs
     deg = eng.out_degree
     ones = np.flatnonzero(deg == 1)
@@ -108,7 +109,7 @@ def test_a_traced_superstep_holds_every_serial_span_nested(store, arcs,
 
 def test_counters_of_a_full_superstep(store):
     plan = store.load_plan()
-    eng = OutOfCoreEngine(store, EngineConfig())
+    eng = OutOfCoreEngine(store, EngineConfig(engine_mode="tiled"))
     session = eng.open_session(PageRank())
     values = session.values.copy()
     stats = session.step()      # superstep 0: every tile runs
@@ -124,8 +125,8 @@ def test_counters_of_a_full_superstep(store):
 
 
 CONFIGS = {
-    "serial": {},
-    "pipelined": dict(pipeline=True, stack_size=2),
+    "serial": dict(engine_mode="tiled"),
+    "pipelined": dict(engine_mode="tiled", pipeline=True, stack_size=2),
     "stacked": dict(engine_mode="stacked"),
     "merged": dict(engine_mode="merged"),
     "ooc-vstate": dict(vertex_memory_budget=4096),

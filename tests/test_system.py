@@ -27,7 +27,9 @@ def test_graphh_end_to_end(tmp_path):
 
     eng = OutOfCoreEngine(store, EngineConfig(
         num_servers=4, cache_capacity_bytes=1 << 22, cache_mode="auto",
-        comm_mode="hybrid", max_supersteps=100))
+        comm_mode="hybrid", max_supersteps=100,
+        # the edge cache serves every superstep on the per-tile path
+        engine_mode="tiled"))
     res = eng.run(PageRank(update_tol=1e-9))
     assert res.converged
 

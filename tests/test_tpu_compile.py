@@ -13,6 +13,7 @@ process that described the topology.
 """
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -110,3 +111,36 @@ def test_fused_min_block_beyond_vmem_is_refused(one_chip):
             > kernel_tune.vmem_budget())
     with pytest.raises(Exception, match="(?i)vmem|resource"):
         _compile_fused(SPECS["min"], 8, (4096, 2048), one_chip)
+
+
+def test_resident_superstep_keeps_the_stacks_in_place(one_chip):
+    """A server's resident superstep at graph500-22's shapes (1,899 tiles
+    of 220,800 slots, 2,396,020 vertices) compiles as one program named
+    for the tile stack, and scans the stacks where they lie: its
+    temporaries are a small share of the 5 GB of arguments."""
+    from repro.core import gab
+    from repro.core.apps import PageRank
+    from repro.core.engine import resident_vertex_bytes
+
+    tiles, slots, nv = 1_899, 220_800, 2_396_020
+    f32, i32 = jnp.float32, jnp.int32
+    stk = {"src": _arr((tiles, slots), i32, one_chip),
+           "dst_local": _arr((tiles, slots), i32, one_chip),
+           "val": _arr((tiles, slots), f32, one_chip),
+           "row_start": _arr((tiles,), i32, one_chip),
+           "num_rows": _arr((tiles,), i32, one_chip)}
+    compiled = gab._jit_run_tile_stack.lower(
+        PageRank(update_tol=1e-9), _arr((nv,), f32, one_chip),
+        {"inv_out_degree": _arr((nv,), f32, one_chip)}, stk, ROWS, "jnp",
+        None).compile()
+    assert "tile_stack" in compiled.as_text().splitlines()[0]
+    mem = compiled.memory_analysis()
+    stacks = tiles * slots * 12 + tiles * 2 * 4
+    assert mem.argument_size_in_bytes > stacks
+    assert mem.temp_size_in_bytes < mem.argument_size_in_bytes / 100
+    # the fit test's vertex bytes hold what the program adds to the stacks
+    values = np.empty(nv, np.float32)
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - stacks)
+    assert resident_vertex_bytes(nv, ROWS, slots, values,
+                                 {"inv_out_degree": values}) >= held
