@@ -10,6 +10,11 @@ file:
     bench/algos/<algorithm>.py  program, plain reference, compared numbers
     bench/metrics/<metric>.py   ``reduce(run)``: one metric from a run record
 
+A configuration's ``graph`` may carry ``"weights"``: absent or ``null`` for
+an unweighted graph, ``"uniform01"`` for Graph500 kernel 3's float32 weights,
+uniform on [0, 1) (bench/graph500.py). The store then holds them as edge
+values, and an algorithm's reference reads them as ``graph.weight``.
+
 ``root`` is the benchmark's directory (this one, or a copy in a test);
 ``BENCHMARK.json`` lies beside it.
 """

@@ -32,10 +32,8 @@ from bench import cells, graph500  # noqa: E402
 def control_readings(cell: cells.Cell, seed: int, supersteps: int) -> dict:
     """The control's compared numbers for ``seed`` (and the reference's own
     traversed edges), at ``supersteps`` supersteps from the first root."""
-    g = cell.config["graph"]
     algo = cell.algorithm
-    graph = graph500.generate(g["scale"], g["edge_factor"], g["seed"], seed,
-                              g["a"], g["b"], g["c"])
+    graph = graph500.of_config(cell.config["graph"], seed)
     out_deg = np.bincount(graph.src, minlength=graph.num_vertices)
     root = algo.roots(graph, out_deg, cell.workload)[0]
     ref, edges = algo.reference(graph, out_deg, root, supersteps)

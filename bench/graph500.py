@@ -22,11 +22,21 @@ The draws are made in chunks of :data:`CHUNK_EDGES` by one jitted function
 (each chunk folds its index into the key, so the chunking is part of the
 graph's definition); the whole list then stays on the device for the sort
 that removes duplicates.
+
+A weighted configuration (``"weights": "uniform01"``) adds Graph500 kernel
+3's edge weights: float32, uniform on [0, 1), drawn from the ``graph_seed``'s
+key folded with :data:`WEIGHT_TAG`, so the arc draws, the scramble and the
+relabelling are the same as without weights. There is one weight per kept
+simple edge, in the sorted order of the distinct edges, and both arcs of the
+edge carry it. Matching a weight to a simple edge, and not to a drawn edge
+before duplicates are removed, is an assumption: the specification draws a
+weight for each drawn edge and leaves duplicates to the kernels. ``--seed``
+moves no weight off its edge, so every seed still does the same work.
 """
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +44,11 @@ import numpy as np
 
 #: edges drawn per jitted call (the whole list when it is shorter)
 CHUNK_EDGES = 1 << 22
+#: folded into the graph's key for the weights: neither the scramble's
+#: ``1 << 31`` nor a chunk index
+WEIGHT_TAG = (1 << 31) + 1
+#: a configuration's ``graph.weights`` values
+WEIGHTS = (None, "uniform01")
 
 
 class Graph(NamedTuple):
@@ -43,6 +58,7 @@ class Graph(NamedTuple):
     src: np.ndarray                # int32 [E]: both arcs of every edge
     dst: np.ndarray                # int32 [E]
     relabel: np.ndarray            # int32 [V]: base vertex id -> vertex id
+    weight: Optional[np.ndarray] = None   # float32 [E], or None: unweighted
 
 
 def prng_key(seed: int) -> jax.Array:
@@ -119,10 +135,23 @@ def _arcs(lo, hi, degree, seed_key, *, num_edges, num_vertices):
     return jnp.concatenate([u, v]), jnp.concatenate([v, u]), relabel
 
 
+@functools.partial(jax.jit, static_argnames=("num_edges",))
+def _weights(weight_key, *, num_edges):
+    """One weight per kept edge, given to both of its arcs (as ``_arcs``
+    orders them)."""
+    w = jax.random.uniform(weight_key, (num_edges,), jnp.float32)
+    return jnp.concatenate([w, w])
+
+
 def generate(scale: int, edge_factor: int, graph_seed: int, seed: int,
-             a: float = 0.57, b: float = 0.19, c: float = 0.19) -> Graph:
+             a: float = 0.57, b: float = 0.19, c: float = 0.19,
+             weights: Optional[str] = None) -> Graph:
     """Make the configuration's graph (``graph_seed``) relabelled by ``seed``
-    on the default device, and copy it to the host."""
+    on the default device, with ``weights`` as :data:`WEIGHTS` names them,
+    and copy it to the host."""
+    if weights not in WEIGHTS:
+        raise ValueError(f"unknown edge weights {weights!r}; known: "
+                         f"{WEIGHTS}")
     nv = 1 << scale
     ne = edge_factor * nv
     chunk = min(CHUNK_EDGES, ne)
@@ -139,5 +168,16 @@ def generate(scale: int, edge_factor: int, graph_seed: int, seed: int,
     src, dst, relabel = _arcs(lo, hi, degree, prng_key(seed),
                               num_edges=int(count), num_vertices=num_vertices)
     del lo, hi
+    weight = None
+    if weights is not None:
+        weight_key = jax.random.fold_in(graph_key, WEIGHT_TAG)
+        weight = np.asarray(_weights(weight_key, num_edges=int(count)))
     return Graph(num_vertices, np.asarray(src), np.asarray(dst),
-                 np.asarray(relabel))
+                 np.asarray(relabel), weight)
+
+
+def of_config(graph: dict, seed: int) -> Graph:
+    """The graph of a configuration file's ``graph`` entry, relabelled by
+    ``seed``."""
+    return generate(graph["scale"], graph["edge_factor"], graph["seed"], seed,
+                    graph["a"], graph["b"], graph["c"], graph.get("weights"))

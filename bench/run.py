@@ -5,7 +5,8 @@
 A run:
 
 1. set-up: makes the cell's Graph500 graph on the device from ``--seed``
-   (bench/graph500.py), hands the arcs to ``spe.preprocess`` into a tile
+   (bench/graph500.py), hands the arcs, and their weights where the
+   configuration has them, to ``spe.preprocess`` into a tile
    store under ``$TMPDIR``, opens ``OutOfCoreEngine`` with the
    configuration's memory budgets, opens a session of the cell's program
    (``engine.open_session``) and steps it through the workload's
@@ -185,11 +186,9 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool, *,
     """Set up, measure and check one run of ``cell``; returns the run record
     the metric reducers read (see bench/metrics/)."""
     cfg, wl, algo = cell.config, cell.workload, cell.algorithm
-    g = cfg["graph"]
     t = time.perf_counter()
     with span("bench.generate"):
-        graph = graph500.generate(g["scale"], g["edge_factor"], g["seed"],
-                                  seed, g["a"], g["b"], g["c"])
+        graph = graph500.of_config(cfg["graph"], seed)
     out_deg = np.bincount(graph.src, minlength=graph.num_vertices)
     generate_s = time.perf_counter() - t
     work = tempfile.mkdtemp(prefix="graphh_bench_")
@@ -197,7 +196,7 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool, *,
         store = TileStore(os.path.join(work, "store"))
         t = time.perf_counter()
         with span("bench.spe"):
-            spe.preprocess_arrays(graph.src, graph.dst, None,
+            spe.preprocess_arrays(graph.src, graph.dst, graph.weight,
                                   graph.num_vertices, store,
                                   tile_size=int(cfg["store"]["tile_edges"]))
         spe_s = time.perf_counter() - t
@@ -260,6 +259,7 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool, *,
         failed=failed, compared=compared, limits=limits,
         memory_peak_bytes=memory_peak, cache_mode=cache_mode,
         num_vertices=graph.num_vertices, num_arcs=len(graph.src),
+        weighted=graph.weight is not None,
         raw_tile_bytes=raw_bytes, num_tiles=plan.num_tiles,
         edge_cap=plan.edge_cap, row_cap=plan.row_cap,
         trace=summary, traced_edges=win["traced_edges"],
@@ -317,7 +317,8 @@ def _describe(run: dict) -> str:
     lines = [
         f"set-up {run['setup_s']:.3f} s: generate {run['generate_s']:.3f}, "
         f"spe {run['spe_s']:.3f}, warm-up {run['warmup_s']:.3f}",
-        f"graph: {run['num_vertices']} vertices, {run['num_arcs']} arcs; "
+        f"graph: {run['num_vertices']} vertices, {run['num_arcs']} arcs"
+        f"{', weighted' if run['weighted'] else ''}; "
         f"store: {run['num_tiles']} tiles, edge_cap {run['edge_cap']}, "
         f"row_cap {run['row_cap']}, {run['raw_tile_bytes']} raw tile bytes, "
         f"cache mode {run['cache_mode']}",

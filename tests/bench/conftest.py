@@ -5,6 +5,7 @@ import json
 import shutil
 import sys
 from pathlib import Path
+from typing import Optional
 
 import pytest
 
@@ -20,20 +21,25 @@ SMALL_TILE_EDGES = 1024
 
 def _add_cell(root: Path, name: str, like: str, config: str,
              scale: int = SMALL_SCALE,
-             tile_edges: int = SMALL_TILE_EDGES) -> str:
+             tile_edges: int = SMALL_TILE_EDGES,
+             weights: Optional[str] = None, **workload) -> str:
     """Add cell ``name`` (configuration ``config``) to the benchmark copy at
-    ``root``, shaped like cell ``like`` at ``scale``: three new files and one
-    BENCHMARK.json entry, nothing else."""
+    ``root``, shaped like cell ``like`` at ``scale``, with the graph's
+    ``weights`` and the cell file's keys in ``workload`` set where given: two
+    new files and one BENCHMARK.json entry, nothing else."""
     bench = json.loads((root.parent / "BENCHMARK.json").read_text())
     base = next(w for w in bench["workloads"] if w["name"] == like)
     cfg = json.loads((root / "configs" / f"{base['config']}.json").read_text())
     cfg["graph"]["scale"] = scale
+    if weights is not None:
+        cfg["graph"]["weights"] = weights
     cfg["store"]["tile_edges"] = tile_edges
     (root / "configs" / f"{config}.json").write_text(json.dumps(cfg))
     wl = json.loads((root / "workloads" / f"{like}.json").read_text())
-    wl["config"] = config
+    wl.update(workload, config=config)
     (root / "workloads" / f"{name}.json").write_text(json.dumps(wl))
-    bench["workloads"].append(dict(base, name=name, config=config))
+    bench["workloads"].append(dict(base, name=name, config=config,
+                                   traffic=wl["algorithm"]))
     (root.parent / "BENCHMARK.json").write_text(json.dumps(bench))
     return name
 

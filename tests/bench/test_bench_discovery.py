@@ -21,6 +21,9 @@ def test_every_cell_loads_by_name(entry):
     assert set(cell.algorithm.LIMITS) and callable(cell.algorithm.reference)
     for trace in (False, True):
         assert cells.metrics(entry["name"], trace)
+    # set-up and at least one more end-to-end metric in every cell
+    names = {m.name for m in cells.metrics(entry["name"], False)}
+    assert "setup_s" in names and len(names) >= 2
 
 
 @pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda e: e["name"])

@@ -1,10 +1,15 @@
 """The harness's Graph500 generator: seeded, deterministic, a simple undirected
-graph skewed as Graph500's, and giving every seed the same tile shapes."""
+graph skewed as Graph500's, giving every seed the same tile shapes, and with
+kernel 3's weights where the configuration asks for them."""
+import hashlib
+
 import numpy as np
 import pytest
 
 from bench import graph500
 from repro.core.partition import plan_partition
+from repro.graphio import spe
+from repro.graphio.formats import TileStore
 
 SCALE = 10
 NV, NE = 1 << SCALE, 16 << SCALE
@@ -84,3 +89,87 @@ def test_large_seeds_do_not_wrap():
     b = graph500.prng_key(5 + (1 << 32))
     import jax
     assert not np.array_equal(jax.random.key_data(a), jax.random.key_data(b))
+
+
+#: sha256 of src, dst and relabel of ``generate(10, 16, 7, seed)`` as the
+#: generator made them before it could draw weights
+UNWEIGHTED_DIGESTS = {
+    1: "5734da4d7e6e8972cf4531a369de93bff98e57f92645ffc9f66858d577b5b034",
+    2**31 + 9:
+        "2e4ba37f7dbc234b8c9ddbeebb4ce78ccddf9a8364a4ae2464ccdbef0f267752",
+}
+
+
+@pytest.fixture(scope="module")
+def weighted():
+    return {seed: graph500.generate(SCALE, 16, 7, seed, weights="uniform01")
+            for seed in (1, 2**31 + 9)}
+
+
+@pytest.mark.parametrize("seed", sorted(UNWEIGHTED_DIGESTS))
+def test_an_unweighted_graph_is_as_before(graphs, weighted, seed):
+    g = graphs[seed]
+    assert g.weight is None
+    h = hashlib.sha256()
+    for a in (g.src, g.dst, g.relabel):
+        h.update(np.ascontiguousarray(a).tobytes())
+    assert h.hexdigest() == UNWEIGHTED_DIGESTS[seed]
+    # drawing the weights moves no arc
+    for a, b in zip(g[1:4], weighted[seed][1:4]):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("seed", [1, 2**31 + 9])
+def test_weights_are_uniform01_float32_and_shared_by_both_arcs(weighted,
+                                                               seed):
+    g = weighted[seed]
+    w = g.weight
+    assert w.dtype == np.float32 and w.shape == g.src.shape
+    assert 0.0 <= w.min() and w.max() < 1.0
+    assert 0.4 < w.mean() < 0.6
+    arc = {(s, d): x for s, d, x in zip(g.src.tolist(), g.dst.tolist(),
+                                        w.tolist())}
+    assert all(arc[d, s] == x for (s, d), x in arc.items())
+
+
+def test_a_graph_seed_gives_the_same_weights(weighted):
+    again = graph500.generate(SCALE, 16, 7, 1, weights="uniform01")
+    np.testing.assert_array_equal(again.weight, weighted[1].weight)
+    other = graph500.generate(SCALE, 16, 8, 1, weights="uniform01")
+    assert not np.array_equal(other.weight[:100], weighted[1].weight[:100])
+
+
+def test_seeds_keep_each_weight_on_its_edge(weighted):
+    """Two seeds give the same weighted edges in base ids: the same multiset
+    of weights, each on the same edge of the isomorphic graphs."""
+    out = []
+    for g in weighted.values():
+        back = np.argsort(g.relabel)       # vertex id -> base id
+        out.append(sorted(zip(back[g.src].tolist(), back[g.dst].tolist(),
+                              g.weight.tolist())))
+    assert out[0] == out[1]
+
+
+def test_unknown_weights_are_refused():
+    with pytest.raises(ValueError):
+        graph500.generate(SCALE, 16, 7, 1, weights="normal")
+
+
+def test_a_zero_weight_survives_spe_into_the_tile(tmp_path):
+    """SSSP relaxes across a weight of 0.0 (a [0, 1) draw over 2**28 edges
+    makes some), so SPE must keep it as the tile's ``val``: only the sink
+    row marks padding there, never ``val == 0``."""
+    src = np.array([0, 1, 1, 2, 3, 0], np.int32)
+    dst = np.array([1, 0, 2, 1, 0, 3], np.int32)
+    val = np.array([0.0, 0.0, 0.25, 0.25, 0.0, 0.0], np.float32)
+    store = TileStore(str(tmp_path / "store"))
+    plan = spe.preprocess_arrays(src, dst, val, 4, store, tile_size=4)
+    got = set()
+    for t in range(plan.num_tiles):
+        tile = store.read_tile(t)
+        n = tile.meta.num_edges
+        assert tile.val is not None
+        got |= set(zip(tile.src[:n].tolist(),
+                       (tile.dst_local[:n] + tile.meta.row_start).tolist(),
+                       tile.val[:n].tolist()))
+    assert got == set(zip(src.tolist(), dst.tolist(), val.tolist()))

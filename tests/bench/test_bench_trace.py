@@ -76,6 +76,16 @@ def test_roofline_counts_real_edges_and_rows():
     assert gab.reduce(dict(run, trace=None)) is None
 
 
+def test_roofline_counts_each_weight_of_a_weighted_run():
+    gab = cells.reducer("gab_roofline")
+    assert gab.least_bytes(100, 10, 1, weighted=True) == 100 * 12 + 10 * 8
+    run = {"trace": {"kernel_s": {"jit__jit_run_tile_stack": 2e-3}},
+           "traced_edges": 1000, "traced_rows": 100, "queries": 1,
+           "weighted": True, "peaks": {"hbm_bytes_per_s": 1e9}}
+    # 12,800 bytes at 1 GB/s is 12.8 us of the tile step's 2 ms
+    assert gab.reduce(run) == pytest.approx(100 * 12.8e-6 / 2e-3)
+
+
 def test_a_trace_without_the_harness_spans_reads_nothing():
     device = """planes { id: 1 name: "/device:TPU:0"
       lines { id: 1 name: "XLA Ops" timestamp_ns: 0

@@ -1,6 +1,7 @@
 """The harness's run on the CPU at scale 10: the engine against the harness's
 references through the window code, the faults the check must catch, the
 configurations' guarantees, and the refusals."""
+import dataclasses
 import json
 import os
 import shutil
@@ -62,29 +63,60 @@ def _frozen_state(monkeypatch):
     monkeypatch.setattr(engine_mod.EngineSession, "step", step)
 
 
-def _half_the_tiles(monkeypatch):
-    real = engine_mod.run_tile
+def _half_the_tiles(monkeypatch, paths=("tile", "stack")):
+    """Rows of every other tile keep their old values: per tile (sparse
+    supersteps, the tiled path) and in a resident stack (dense supersteps
+    since the store fits the device)."""
+    if "tile" in paths:
+        real = engine_mod.run_tile
 
-    def run_tile(prog, values, aux, arrays, row_start, *rest):
-        rows, new, upd = real(prog, values, aux, arrays, row_start, *rest)
-        if row_start % 2:
-            upd = jnp.zeros_like(upd)
-        return rows, new, upd
-    monkeypatch.setattr(engine_mod, "run_tile", run_tile)
+        def run_tile(prog, values, aux, arrays, row_start, *rest):
+            rows, new, upd = real(prog, values, aux, arrays, row_start, *rest)
+            if row_start % 2:
+                upd = jnp.zeros_like(upd)
+            return rows, new, upd
+        monkeypatch.setattr(engine_mod, "run_tile", run_tile)
+    if "stack" in paths:
+        real_stack = engine_mod.run_tile_stack
+
+        def run_tile_stack(prog, values, aux, stk, *rest):
+            num_rows = jnp.asarray(stk["num_rows"]).at[1::2].set(0)
+            return real_stack(prog, values, aux, dict(stk, num_rows=num_rows),
+                              *rest)
+        monkeypatch.setattr(engine_mod, "run_tile_stack", run_tile_stack)
 
 
-def _altered_answer(monkeypatch):
-    real = engine_mod.run_tile
+def _alter_first_row(new, upd):
+    v = new[0]
+    return (new.at[0].set(jnp.where(jnp.isfinite(v), v * 1.01 + 0.01, 0.5)),
+            upd.at[0].set(True))
 
-    def run_tile(prog, values, aux, arrays, row_start, *rest):
-        rows, new, upd = real(prog, values, aux, arrays, row_start, *rest)
-        if row_start == 0:
-            v = new[0]
-            new = new.at[0].set(jnp.where(jnp.isfinite(v), v * 1.01 + 0.01,
-                                          0.5))
-            upd = upd.at[0].set(True)
-        return rows, new, upd
-    monkeypatch.setattr(engine_mod, "run_tile", run_tile)
+
+def _altered_answer(monkeypatch, paths=("tile", "stack")):
+    """The first row's new value is altered where it is produced: in the
+    tile that starts at row 0, and in a resident stack's result."""
+    if "tile" in paths:
+        real = engine_mod.run_tile
+
+        def run_tile(prog, values, aux, arrays, row_start, *rest):
+            rows, new, upd = real(prog, values, aux, arrays, row_start, *rest)
+            if row_start == 0:
+                new, upd = _alter_first_row(new, upd)
+            return rows, new, upd
+        monkeypatch.setattr(engine_mod, "run_tile", run_tile)
+    if "stack" in paths:
+        real_stack = engine_mod.run_tile_stack
+
+        def run_tile_stack(*args):
+            return _alter_first_row(*real_stack(*args))
+        monkeypatch.setattr(engine_mod, "run_tile_stack", run_tile_stack)
+
+
+def _assert_fails_its_comparison(line):
+    c = line["compared"]["max_rel_err"]
+    assert c["value"] > c["limit"]
+    assert line["correct"] is False
+    assert line["failed"] >= 1
 
 
 @pytest.mark.parametrize("fault", [_frozen_state, _half_the_tiles,
@@ -92,8 +124,34 @@ def _altered_answer(monkeypatch):
 def test_a_broken_timed_path_is_not_correct(bench_copy, monkeypatch, fault):
     fault(monkeypatch)
     _, line = _run(bench_copy, "small.pr")
-    assert line["correct"] is False
-    assert line["failed"] >= 1
+    _assert_fails_its_comparison(line)
+
+
+@pytest.mark.parametrize("path,mode", [("stack", "stacked"),
+                                       ("tile", "tiled")])
+@pytest.mark.parametrize("fault", [_half_the_tiles, _altered_answer])
+def test_a_fault_on_one_path_alone_is_not_correct(bench_copy, monkeypatch,
+                                                  fault, path, mode):
+    """Each path's fault alone turns the run incorrect, the other path left
+    as it is: the resident stack in the cell's own engine mode, the per-tile
+    entry with the engine pinned to its tiled path."""
+    if mode == "tiled":
+        real = run._engine_config
+        monkeypatch.setattr(run, "_engine_config", lambda cfg, trace: (
+            dataclasses.replace(real(cfg, trace), engine_mode="tiled")))
+    modes = []
+    real_open = engine_mod.OutOfCoreEngine.open_session
+
+    def open_session(self, *args, **kw):
+        session = real_open(self, *args, **kw)
+        modes.append(session.engine_mode)
+        return session
+    monkeypatch.setattr(engine_mod.OutOfCoreEngine, "open_session",
+                        open_session)
+    fault(monkeypatch, paths=(path,))
+    _, line = _run(bench_copy, "small.pr")
+    assert set(modes) == {mode}
+    _assert_fails_its_comparison(line)
 
 
 def test_edge_cache_over_capacity_is_not_correct(bench_copy, monkeypatch):
